@@ -61,6 +61,7 @@ import sys
 import time
 from typing import List, Sequence
 
+from repro.compile_cache import configure_compile_cache
 from repro.core.sim.batch import report_digest
 from repro.scenarios.runner import ScenarioSpec, run as run_specs
 from repro.scenarios.script import (
@@ -88,10 +89,19 @@ def run_cell_distributional(
     scenario: str, policy: str, seeds: Sequence[int], ks_tol: float
 ) -> dict:
     """SoA-vs-scalar statistical verdicts for one scenario x policy
-    cell: exact structural invariants, pooled chain-latency KS, and CI
-    overlap on the summary rates.  The scalar side is driven through
-    the lockstep engine, whose bit-identity to the scalar backend the
-    bitwise mode of this gate pins separately."""
+    cell (:func:`compare_distributional`).  The scalar side is driven
+    through the lockstep engine, whose bit-identity to the scalar
+    backend the bitwise mode of this gate pins separately."""
+    spec = ScenarioSpec(scenario=get_scenario(scenario), policy=policy)
+    ref = run_specs(spec, seeds=list(seeds), backend="lockstep")
+    soa = run_specs(spec, seeds=list(seeds), backend="soa", fallback=False)
+    return compare_distributional(ref, soa, ks_tol)
+
+
+def compare_distributional(ref, soa, ks_tol: float) -> dict:
+    """Verdicts of SoA reports ``soa`` against oracle reports ``ref`` of
+    the same seeds: exact structural invariants per seed, pooled
+    chain-latency KS, and CI overlap on the summary rates."""
     from repro.core.sim.soa import (
         intervals_overlap,
         ks_statistic,
@@ -99,27 +109,25 @@ def run_cell_distributional(
         structural_invariants,
     )
 
-    spec = ScenarioSpec(scenario=get_scenario(scenario), policy=policy)
-    ref = run_specs(spec, seeds=list(seeds), backend="lockstep")
-    soa = run_specs(spec, seeds=list(seeds), backend="soa", fallback=False)
     struct_ok = all(
         structural_invariants(a) == structural_invariants(b) for a, b in zip(ref, soa)
     )
     lat_ref = [x for r in ref for ls in r.chain_latencies.values() for x in ls]
     lat_soa = [x for r in soa for ls in r.chain_latencies.values() for x in ls]
     ks = ks_statistic(lat_ref, lat_soa)
-    ci_ok = True
+    ci = {}
     for metric in ("violation_rate", "realloc_frac", "tiles_reserved_mean"):
         ci_ref = mean_ci([getattr(r, metric) for r in ref])
         ci_soa = mean_ci([getattr(r, metric) for r in soa])
         # zero-width intervals (deterministic metrics, single seeds)
         # still must touch: pad by a rounding epsilon only
-        ci_ok = ci_ok and intervals_overlap(ci_ref, ci_soa, pad=1e-9)
+        ci[metric] = (ci_ref, ci_soa, intervals_overlap(ci_ref, ci_soa, pad=1e-9))
     return {
         "struct_ok": struct_ok,
         "ks": ks,
         "ks_ok": ks <= ks_tol,
-        "ci_ok": ci_ok,
+        "ci": ci,
+        "ci_ok": all(ok for _r, _s, ok in ci.values()),
         "n": (len(lat_ref), len(lat_soa)),
     }
 
@@ -186,21 +194,13 @@ def main(argv=None) -> int:
         "on the pinned B=8 perf scenario (ads_tile)",
     )
     args = ap.parse_args(argv)
+    configure_compile_cache()
 
     scenarios = (
         sorted(BUNDLED_SCENARIOS) if args.scenarios == ["all"] else args.scenarios
     )
 
     if args.mode == "distributional":
-        from repro.core.sim.soa import soa_available
-
-        if not soa_available():
-            print(
-                "distributional mode needs jax (the SoA backend); "
-                "skipping gate",
-                file=sys.stderr,
-            )
-            return 0
         lines = [
             "| scenario | policy | struct | KS (tol) | CI overlap |",
             "|---|---|---|---|---|",

@@ -264,18 +264,15 @@ def run(duration: float = 1.0, seed: int = 1) -> None:
     )
 
     # -- part 3: tiles-saved-vs-load curve (Fig. 13 analogue) -----------
-    from repro.core.sim.soa import soa_available
-
     script3 = gen.sample(2.0, seed=seed * 100003)  # one pinned bursty drive
     seeds3 = list(range(seed, seed + n))
-    backend3 = "soa" if soa_available() else "lockstep"
 
     def cell_stats(spec):
         """(mean violation rate, mean reserved tiles) over the R-seed
-        cell — SoA lanes when jax is present, lockstep lanes otherwise
-        via run()'s per-spec fallback (the curve is a statistical
-        statement either way)."""
-        reports = run_specs(spec, seeds=seeds3, backend=backend3)
+        cell on the SoA lanes (lockstep lanes via run()'s per-spec
+        fallback where the SoA kernels do not apply; the curve is a
+        statistical statement either way)."""
+        reports = run_specs(spec, seeds=seeds3, backend="soa")
         return (
             mean([r.violation_rate for r in reports]),
             mean([r.tiles_reserved_mean for r in reports]),

@@ -260,13 +260,7 @@ def _soa_benchmark(duration: float, seed: int) -> None:
     same-process lockstep per-run time; see
     docs/performance.md#soa-backend for why the single-core envelope
     of this ratio is modest (the round kernel's op-dispatch cost does
-    not amortize with R on one core) and where the backend does win.
-    Skips (emitting nothing) when jax is unavailable."""
-    from repro.core.sim.soa import soa_available
-
-    if not soa_available():
-        print("perf_soa_*: jax unavailable, skipping SoA rows")
-        return
+    not amortize with R on one core) and where the backend does win."""
     gen = MarkovScenarioGenerator(transitions=PERF_TRANSITIONS, mean_dwell_s=PERF_DWELL)
     scen = gen.sample(2.0, seed)
     for pol, name in (("ads_tile", "perf_soa_ads"), ("tp_driven", "perf_soa_tp")):

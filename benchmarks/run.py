@@ -11,9 +11,10 @@ One module per paper artifact (DESIGN.md §7):
   roofline — §Roofline table from the dry-run artifacts
 
 ``--only fig11`` runs a subset; ``--duration`` scales simulated seconds
-(default keeps the full harness under ~15 min on this CPU container);
+(default keeps the full harness under ~15 min on one CPU core);
 ``--jobs N`` runs independent suites in N worker processes (suite
-output is buffered per process and printed in order).
+output is buffered per process and printed in order; workers run on
+the CPU, since an accelerator belongs to one process).
 """
 from __future__ import annotations
 
@@ -25,6 +26,7 @@ import sys
 import time
 from pathlib import Path
 
+from repro.compile_cache import configure_compile_cache
 from repro.obs import metrics
 
 from . import fig6_casestudy, fig11_ablation, fig12_e2e, fig13_scaling
@@ -185,6 +187,7 @@ def main() -> None:
                          "subprocesses via the manifest instead of the "
                          "in-process pool")
     args = ap.parse_args()
+    configure_compile_cache()
     if args.campaign and args.campaign_manifest is None:
         args.campaign_manifest = str(
             Path(args.campaign_cache) / "manifest.json"

@@ -20,7 +20,6 @@ from .soa import (
     SoaOptions,
     SoaUnsupported,
     SoaWindowOverflow,
-    soa_available,
     soa_supported,
 )
 from .trace import Trace, build_skeleton, counter_uniforms, sample_trace
@@ -37,7 +36,6 @@ __all__ = [
     "SoaOptions",
     "SoaUnsupported",
     "SoaWindowOverflow",
-    "soa_available",
     "soa_supported",
     "Trace",
     "build_skeleton",

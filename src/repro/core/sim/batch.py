@@ -45,6 +45,8 @@ import heapq
 import math
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ...obs import metrics
@@ -136,11 +138,7 @@ def _uniforms_batch(
     becomes a (B, 1) column, everything after it broadcasts elementwise
     — so row ``k`` equals the scalar ``_uniforms_from_keys(seeds[k],
     ...)`` bit-for-bit."""
-    h = np.asarray(
-        [_mix64_int(_mix64_int((s & _MASK64) ^ int(_GOLDEN)) ^ stream) for s in seeds],
-        dtype=np.uint64,
-    ).reshape(-1, 1)
-    v = _mix64(h ^ task_keys)
+    v = _mix64(_seed_keys(seeds, stream) ^ task_keys)
     v = _mix64(v ^ (regime + _GOLDEN))
     v = _mix64(v ^ (cycle * _C_CYCLE + _U64(1)))
     v = _mix64(v ^ (idx * _C_IDX + _U64(2)))
@@ -150,19 +148,18 @@ def _uniforms_batch(
 # ---------------------------------------------------------------------------
 # on-device (jnp) sampling path — used by the SoA backend
 # ---------------------------------------------------------------------------
-try:  # jax is a runtime dep, but keep the lockstep engine usable without it
-    import jax as _jax  # noqa: F401
-    import jax.numpy as _jnp
-    from jax.experimental import enable_x64 as _enable_x64
-
-    _HAS_JAX = True
-except Exception:  # pragma: no cover - exercised on jax-less platforms
-    _HAS_JAX = False
+def _seed_keys(seeds: Sequence[int], stream: int) -> np.ndarray:
+    """(B, 1) uint64 per-lane key of one stream: the scalar seed fold,
+    in exact Python-int arithmetic on the host for both paths."""
+    return np.asarray(
+        [_mix64_int(_mix64_int((s & _MASK64) ^ int(_GOLDEN)) ^ stream) for s in seeds],
+        dtype=np.uint64,
+    ).reshape(-1, 1)
 
 
 def _mix64_jnp(x):
     """splitmix64 finalizer on jnp ``uint64`` (requires x64 mode)."""
-    u = _jnp.uint64
+    u = jnp.uint64
     x = x ^ (x >> u(30))
     x = x * u(int(_M1_INT))
     x = x ^ (x >> u(27))
@@ -177,80 +174,99 @@ def _ndtri_jnp(q):
     uniforms are ``(m + 0.5) * 2**-53`` whose supremum ``1 - 2**-54``
     rounds to exactly 1.0 in binary64, so ``q >= 1.0`` is a reachable
     input (probability ~1e-16 per draw) and must map to ``+inf`` like
-    the NumPy path, not to the clip's finite tail value."""
-    qc = _jnp.clip(q, 1e-300, 1.0 - 1e-16)
-    lo_t = _ndtri_tail(_jnp.sqrt(-2.0 * _jnp.log(qc)))
-    hi_t = -_ndtri_tail(_jnp.sqrt(-2.0 * _jnp.log(1.0 - qc)))
-    out = _jnp.where(
-        q < _NDTRI_PLOW,
-        lo_t,
-        _jnp.where(q > 1.0 - _NDTRI_PLOW, hi_t, _ndtri_central(qc)),
+    the NumPy path, not to the clip's finite tail value.  Both tails
+    share one log/sqrt evaluation (on ``qc`` or ``1 - qc``, the NumPy
+    branches' own inputs): float64 is emulated on a TPU, and each
+    transcendental costs compile time there."""
+    qc = jnp.clip(q, 1e-300, 1.0 - 1e-16)
+    low = qc < 0.5
+    t = _ndtri_tail(jnp.sqrt(-2.0 * jnp.log(jnp.where(low, qc, 1.0 - qc))))
+    out = jnp.where(
+        (q < _NDTRI_PLOW) | (q > 1.0 - _NDTRI_PLOW),
+        jnp.where(low, t, -t),
+        _ndtri_central(qc),
     )
-    return _jnp.where(
-        q <= 0.0, -_jnp.inf, _jnp.where(q >= 1.0, _jnp.inf, out)
+    return jnp.where(
+        q <= 0.0, -jnp.inf, jnp.where(q >= 1.0, jnp.inf, out)
     )
 
 
-def _uniforms_batch_jnp(seeds, stream, task_keys, regime, cycle, idx):
-    """Device mirror of :func:`_uniforms_batch`: the scalar seed fold
-    stays on host (exact Python-int arithmetic), the broadcast mix runs
-    as jnp uint64 ops.  The integer pipeline is bit-identical to the
-    NumPy path; only the float transforms downstream may differ in the
-    last ulp (XLA's exp/log are not libm)."""
-    h = _jnp.asarray(
-        [_mix64_int(_mix64_int((s & _MASK64) ^ int(_GOLDEN)) ^ stream) for s in seeds],
-        dtype=_jnp.uint64,
-    ).reshape(-1, 1)
-    u = _jnp.uint64
-    v = _mix64_jnp(h ^ task_keys)
-    v = _mix64_jnp(v ^ (regime + u(int(_GOLDEN))))
-    v = _mix64_jnp(v ^ (cycle * u(int(_C_CYCLE)) + u(1)))
-    v = _mix64_jnp(v ^ (idx * u(int(_C_IDX)) + u(2)))
-    return ((v >> u(11)).astype(_jnp.float64) + 0.5) * (2.0**-53)
+def _uniforms_jnp(h, jobs):
+    """Device mirror of :func:`_uniforms_batch` for the per-lane keys
+    ``h`` (:func:`_seed_keys`): the integer pipeline is bit-identical
+    to the NumPy path; only the float transforms downstream may differ
+    in the last bits (XLA's exp/log are not libm)."""
+    u = jnp.uint64
+    v = _mix64_jnp(h ^ jobs["keys"])
+    v = _mix64_jnp(v ^ (jobs["regime"] + u(int(_GOLDEN))))
+    v = _mix64_jnp(v ^ (jobs["cycle"] * u(int(_C_CYCLE)) + u(1)))
+    v = _mix64_jnp(v ^ (jobs["idx"] * u(int(_C_IDX)) + u(2)))
+    return ((v >> u(11)).astype(jnp.float64) + 0.5) * (2.0**-53)
+
+
+def _lognormal_jnp(u, jobs):
+    vals = jnp.exp(jobs["mu"] + jobs["sigma"] * _ndtri_jnp(u))
+    return jnp.where(
+        jobs["mean"] <= 0.0, 0.0,
+        jnp.where(jobs["sigma"] <= 0.0, jobs["mean"], vals),
+    )
+
+
+@jax.jit
+def _device_draws(h_work, h_io, h_sensor, dnn, sen):
+    """Every lane's draws in one compiled pass: ``(work, io)`` over the
+    DNN jobs and ``sensor_lat`` over the sensor jobs, each ``(B, n_*)``
+    float64.  ``h_*`` are the per-stream lane keys; ``dnn``/``sen`` map
+    stream coordinates and distribution parameters to per-job arrays
+    (see :func:`_device_jobs`).  Call it under ``jax.enable_x64(True)``
+    so the quantile transforms run in float64: about 1e-12 relative off
+    the NumPy path on a CPU, about 1e-10 on a TPU, which emulates
+    float64."""
+    work = _lognormal_jnp(_uniforms_jnp(h_work, dnn), dnn) * dnn["burst"]
+    rate = dnn["io_rate"]
+    safe = jnp.where(rate > 0.0, rate, 1.0)
+    ui = _uniforms_jnp(h_io, dnn)
+    queue = -jnp.log(jnp.maximum(1.0 - ui, 1e-300)) / safe
+    io = dnn["io_base"] + jnp.where(rate > 0.0, queue, 0.0)
+    u_sen = 0.001 + 0.998 * _uniforms_jnp(h_sensor, sen)
+    return work, io, _lognormal_jnp(u_sen, sen)
+
+
+def _device_jobs(skel, par, ix) -> Dict[str, np.ndarray]:
+    """Per-job inputs of :func:`_device_draws` for the jobs ``ix``."""
+    return {
+        "keys": skel.task_keys[ix],
+        "regime": skel.regime_arr[ix],
+        "cycle": skel.cycle_arr[ix],
+        "idx": skel.idx_arr[ix],
+        "mean": par.mean[ix],
+        "mu": par.mu[ix],
+        "sigma": par.sigma[ix],
+        "burst": skel.burst[ix],
+        "io_rate": par.io_rate[ix],
+        "io_base": par.io_base[ix],
+    }
 
 
 def _sample_trace_batch_jnp(skel, par, seeds):
-    """All R lanes' draws in one on-device pass (float64 via the x64
-    context so the quantile transforms match the NumPy path to the
-    ulp).  Returns host ndarrays — BatchTrace consumers are NumPy."""
+    """All R lanes' draws through :func:`_device_draws`.  Returns host
+    ndarrays — BatchTrace consumers are NumPy."""
     B, n = len(seeds), skel.n
     work = np.zeros((B, n), dtype=np.float64)
     io = np.zeros((B, n), dtype=np.float64)
     sensor_lat = np.zeros((B, n), dtype=np.float64)
-    with _enable_x64():
-        d = skel.dnn_ix
-        if d.size and B:
-            keys = _jnp.asarray(skel.task_keys[d])
-            reg = _jnp.asarray(skel.regime_arr[d])
-            cyc = _jnp.asarray(skel.cycle_arr[d])
-            idx = _jnp.asarray(skel.idx_arr[d])
-            uw = _uniforms_batch_jnp(seeds, STREAM_WORK, keys, reg, cyc, idx)
-            ui = _uniforms_batch_jnp(seeds, STREAM_IO, keys, reg, cyc, idx)
-            mean = _jnp.asarray(par.mean[d])
-            sigma = _jnp.asarray(par.sigma[d])
-            vals = _jnp.exp(_jnp.asarray(par.mu[d]) + sigma * _ndtri_jnp(uw))
-            w = _jnp.where(mean <= 0.0, 0.0, _jnp.where(sigma <= 0.0, mean, vals))
-            work[:, d] = np.asarray(w * _jnp.asarray(skel.burst[d]))
-            rate = _jnp.asarray(par.io_rate[d])
-            safe = _jnp.where(rate > 0.0, rate, 1.0)
-            queue = -_jnp.log(_jnp.maximum(1.0 - ui, 1e-300)) / safe
-            io[:, d] = np.asarray(
-                _jnp.asarray(par.io_base[d]) + _jnp.where(rate > 0.0, queue, 0.0)
-            )
-
-        s = skel.sen_ix
-        if s.size and B:
-            keys = _jnp.asarray(skel.task_keys[s])
-            reg = _jnp.asarray(skel.regime_arr[s])
-            cyc = _jnp.asarray(skel.cycle_arr[s])
-            idx = _jnp.asarray(skel.idx_arr[s])
-            u_ = _uniforms_batch_jnp(seeds, STREAM_SENSOR, keys, reg, cyc, idx)
-            u_ = 0.001 + 0.998 * u_
-            mean = _jnp.asarray(par.mean[s])
-            sigma = _jnp.asarray(par.sigma[s])
-            vals = _jnp.exp(_jnp.asarray(par.mu[s]) + sigma * _ndtri_jnp(u_))
-            lat = _jnp.where(mean <= 0.0, 0.0, _jnp.where(sigma <= 0.0, mean, vals))
-            sensor_lat[:, s] = np.asarray(lat)
+    d, s = skel.dnn_ix, skel.sen_ix
+    with jax.enable_x64(True):
+        w, i, lat = _device_draws(
+            _seed_keys(seeds, STREAM_WORK),
+            _seed_keys(seeds, STREAM_IO),
+            _seed_keys(seeds, STREAM_SENSOR),
+            _device_jobs(skel, par, d),
+            _device_jobs(skel, par, s),
+        )
+        work[:, d] = np.asarray(w)
+        io[:, d] = np.asarray(i)
+        sensor_lat[:, s] = np.asarray(lat)
     return work, io, sensor_lat
 
 
@@ -267,9 +283,9 @@ def sample_trace_batch(
     ``device=True`` routes the pass through jnp (the SoA backend's
     path): same stream contract, same integer hash bit-for-bit, but
     the float quantile transforms run on-device and may differ from
-    the NumPy path in the last ulp — fine under the distributional
+    the NumPy path in the last bits — fine under the distributional
     equivalence contract, not for the lockstep engine's bit-identity
-    gate.  Falls back to NumPy when jax is unavailable.
+    gate.
     """
     with metrics.phase("trace_sample"):
         seeds = tuple(int(s) for s in seeds)
@@ -279,7 +295,7 @@ def sample_trace_batch(
         # helper, so each lane is bit-identical to sample_trace)
         drops = tuple(storm_drops(skel, scenario, s) for s in seeds)
         storm = None if all(d is None for d in drops) else drops
-        if device and _HAS_JAX:
+        if device:
             work, io, sensor_lat = _sample_trace_batch_jnp(skel, par, seeds)
             return BatchTrace(
                 skeleton_key=skel.key,

@@ -43,7 +43,6 @@ __all__ = [
     "SoaOptions",
     "SoaUnsupported",
     "SoaWindowOverflow",
-    "soa_available",
     "soa_supported",
     "build_problem",
     "run_problem",
@@ -54,11 +53,6 @@ __all__ = [
 ]
 
 _TOL = 1e-9
-
-
-def soa_available() -> bool:
-    """True when jax is importable (the backend's only extra dep)."""
-    return K.HAS_JAX
 
 
 class SoaUnsupported(ValueError):
@@ -140,7 +134,6 @@ class SoaOptions:
     alloc_iters: Optional[int] = None
     bump_passes: int = 8        # tp work-conserving refinement steps
     use_pallas: bool = False    # route the grant select through Pallas
-    pallas_interpret: bool = True
 
 
 @dataclasses.dataclass
@@ -521,8 +514,7 @@ def build_problem(
             else (8 if policy_name == "tp_driven" else 3)
         ),
         bump_passes=int(opt.bump_passes),
-        use_pallas=bool(opt.use_pallas and K.HAS_PALLAS),
-        pallas_interpret=bool(opt.pallas_interpret),
+        use_pallas=bool(opt.use_pallas),
     )
 
     # ---- report-assembly side data ------------------------------------
@@ -627,8 +619,6 @@ def run_problem(
 ) -> List[SimReport]:
     """Advance all lanes through the compiled round loop and assemble
     one scalar-shaped :class:`SimReport` per seed."""
-    if not K.HAS_JAX:
-        raise SoaUnsupported("jax is not available; use backend='lockstep'")
     if problem.cfg.R != len(seeds):
         raise ValueError(
             f"problem compiled for R={problem.cfg.R}, got {len(seeds)} seeds"

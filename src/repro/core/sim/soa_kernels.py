@@ -33,10 +33,6 @@ contract with the scalar engine is **distributional** (KS + CI overlap
 + exact structural invariants), enforced by
 ``benchmarks.check_equivalence --mode distributional`` — see
 ``docs/performance.md#soa-backend`` for what is and is not guaranteed.
-
-jax is an optional dependency of the sim package: importing this module
-without jax leaves ``HAS_JAX`` False and every entry point raising, so
-the scalar/lockstep engines (and their tests) never notice.
 """
 from __future__ import annotations
 
@@ -45,31 +41,13 @@ import hashlib
 from functools import partial
 from typing import Dict, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
-
-try:  # pragma: no cover - exercised via HAS_JAX gates in tests
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-
-    HAS_JAX = True
-except Exception:  # pragma: no cover
-    jax = None
-    jnp = None
-    lax = None
-    HAS_JAX = False
-
-try:  # pragma: no cover
-    from jax.experimental import pallas as pl
-
-    HAS_PALLAS = HAS_JAX
-except Exception:  # pragma: no cover
-    pl = None
-    HAS_PALLAS = False
+from jax import lax
+from jax.experimental import pallas as pl
 
 __all__ = [
-    "HAS_JAX",
-    "HAS_PALLAS",
     "KernelConfig",
     "NFIELDS",
     "F_STATE",
@@ -88,6 +66,7 @@ __all__ = [
     "DONE",
     "DROP",
     "POLICY_IDS",
+    "round_loop",
     "simulate",
     "ladder_grant_reference",
     "clear_kernel_cache",
@@ -155,7 +134,6 @@ class KernelConfig:
     alloc_iters: int = 8       # monotone EDF-allocation refinement steps
     bump_passes: int = 8       # tp work-conserving bump refinement steps
     use_pallas: bool = False   # route _alloc_ladder through Pallas
-    pallas_interpret: bool = True
 
 
 # ---------------------------------------------------------------------------
@@ -175,26 +153,49 @@ def _ladder_grant(limit, cand):
     return jnp.max(jnp.where(ok, cand, 0.0), axis=-1)
 
 
-def _ladder_grant_pallas(limit, cand, interpret=True):
-    """Pallas version of :func:`_ladder_grant` (one lane-block per grid
-    step).  Same math, kept for platforms where a fused scalar loop
-    beats XLA's reduce; on CPU it only runs in interpret mode (tests),
-    the jnp path stays the performance default."""
+#: lanes per grid step of the Pallas grant.  The job window is the
+#: 128-wide lane axis and the ladder the leading axis, so a (C, 512, W)
+#: candidate block takes C x 256 KiB of VMEM (W padded to 128 lanes),
+#: double-buffered well inside the 16 MiB scoped limit at C = 6.
+_GRANT_BLOCK_R = 512
+
+
+def _ladder_grant_pallas(limit, cand, interpret=False):
+    """Pallas version of :func:`_ladder_grant`, bit-identical to it.
+
+    The grid walks blocks of lanes; the C-wide ladder is unrolled as C
+    elementwise (lanes, W) planes instead of being reduced along the
+    lane axis.  ``cand`` is (W, C), one ladder per job shared by every
+    lane (the round loop's case, kept as one resident block), or
+    (R, W, C).  ``interpret=True`` runs it without a TPU (tests)."""
     R, W = limit.shape
-    cand3 = jnp.broadcast_to(cand, (R,) + cand.shape[-2:])
+    C = cand.shape[-1]
+    tr = min(R, _GRANT_BLOCK_R)
+    if cand.ndim == 2:
+        cand_t = cand.T[:, None, :]
+        cand_spec = pl.BlockSpec((C, 1, W), lambda i: (0, 0, 0))
+    else:
+        cand_t = jnp.moveaxis(cand, -1, 0)
+        cand_spec = pl.BlockSpec((C, tr, W), lambda i: (0, i, 0))
 
     def kernel(limit_ref, cand_ref, out_ref):
-        lim = limit_ref[...]
-        cd = cand_ref[...]
-        ok = cd <= lim[..., None] + 0.5
-        out_ref[...] = jnp.max(jnp.where(ok, cd, 0.0), axis=-1)
+        lim = limit_ref[...] + 0.5
+        out = None
+        for c in range(C):
+            cd = cand_ref[c]
+            g = jnp.where(cd <= lim, cd, 0.0)
+            out = g if out is None else jnp.maximum(out, g)
+        out_ref[...] = out
 
+    row_spec = pl.BlockSpec((tr, W), lambda i: (i, 0))
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((R, W), limit.dtype),
-        grid=(1,),
+        grid=(pl.cdiv(R, tr),),
+        in_specs=[row_spec, cand_spec],
+        out_specs=row_spec,
         interpret=interpret,
-    )(limit, cand3)
+    )(limit, cand_t)
 
 
 def ladder_grant_reference(limit: np.ndarray, cand: np.ndarray) -> np.ndarray:
@@ -209,34 +210,27 @@ def _class_prefix(cfg, part_s, cap_p, dtype):
     Returns ``(excl, total, capg)``: ``excl(d)`` is each entry's
     exclusive prefix sum of ``d`` over earlier same-partition entries,
     ``total(d)`` the inclusive whole-partition sum seen by each entry,
-    and ``capg`` the entry's own partition budget.  With one partition
-    these are a plain cumsum / broadcast sum; multi-partition uses a
-    same-partition strict-lower mask as a batched matvec."""
-    if cfg.P == 1:
-        capg = jnp.broadcast_to(cap_p[:, :1], part_s.shape)
+    and ``capg`` the entry's own partition budget.
 
-        def excl(d):
-            return jnp.cumsum(d, axis=1) - d
-
-        def total(d):
-            return jnp.broadcast_to(
-                jnp.sum(d, axis=1, keepdims=True), d.shape
-            )
-
-        return excl, total, capg
-
+    Each partition's sums run over a one-hot (R, P, W) split of ``d``
+    (the window stays the minor axis): O(P W) per lane, so a window
+    widened to the whole horizon stays affordable.  ``d`` holds tile
+    counts, integers that f32 adds exactly in any order, so the result
+    does not depend on how the backend orders or tiles the sums (a
+    matmul form would, on a TPU: its default f32 precision is one bf16
+    pass)."""
     part_i = jnp.clip(part_s.astype(jnp.int32), 0, cfg.P - 1)
-    same = (part_i[:, :, None] == part_i[:, None, :]).astype(dtype)
-    W = part_i.shape[1]
-    tril = jnp.tril(jnp.ones((W, W), dtype=dtype), k=-1)
-    Mpre = same * tril[None]
+    ar_p = jnp.arange(cfg.P, dtype=jnp.int32)[None, :, None]
+    onehot = (part_i[:, None, :] == ar_p).astype(dtype)
     capg = jnp.take_along_axis(cap_p, part_i, axis=1)
 
     def excl(d):
-        return jnp.einsum("rjk,rk->rj", Mpre, d)
+        x = onehot * d[:, None, :]
+        return jnp.sum((jnp.cumsum(x, axis=2) - x) * onehot, axis=1)
 
     def total(d):
-        return jnp.einsum("rjk,rk->rj", same, d)
+        tot = jnp.sum(onehot * d[:, None, :], axis=2)
+        return jnp.take_along_axis(tot, part_i, axis=1)
 
     return excl, total, capg
 
@@ -260,14 +254,9 @@ def _alloc_ladder(cfg, want, entry, part_s, cand_s, cap_p):
     """
     want = jnp.where(entry, want, 0.0)
     cur = want
-    sel = (
-        partial(_ladder_grant_pallas, interpret=cfg.pallas_interpret)
-        if (cfg.use_pallas and HAS_PALLAS)
-        else _ladder_grant
-    )
-    # the per-partition exclusive prefix ("tiles my EDF predecessors in
-    # my partition already took") is one fused op per iteration instead
-    # of P masked cumsums
+    sel = _ladder_grant_pallas if cfg.use_pallas else _ladder_grant
+    # the per-partition exclusive prefix: "tiles my EDF predecessors in
+    # my partition already took"
     excl, _, capg = _class_prefix(cfg, part_s, cap_p, want.dtype)
 
     def step(cur):
@@ -864,6 +853,46 @@ def _const_digest(const_np: Dict[str, np.ndarray]) -> bytes:
     return h.digest()
 
 
+def round_loop(cfg: KernelConfig, const_np: Dict[str, np.ndarray]):
+    """The jitted round loop of one problem: ``(work, io, codes0)`` ->
+    the final ``(state planes, codes, stall_end, busy, realloc,
+    n_realloc, realloc_bytes, dropped_work)``.
+
+    ``const_np`` holds the host-precomputed statics (see
+    :func:`repro.core.sim.soa.build_problem`), closed over as
+    compile-time constants; the lane shapes come from the arguments.
+    Nothing is traced before the first call or ``.lower(...)``, so the
+    loop can be compiled ahead of time for a device that is described
+    rather than attached.
+    """
+    const = {k: jnp.asarray(v) for k, v in const_np.items()}
+    S_ = int(const["caps"].shape[0])
+    P = cfg.P
+
+    @jax.jit
+    def run(work, io, codes0):
+        R, N = work.shape
+        cdev = dict(const)
+        cdev["work"] = work
+        cdev["io"] = io
+        loop = _build_loop(cfg, cdev)
+        zeros = jnp.zeros((R, N), dtype=jnp.float32)
+        inf = jnp.full((R, N), jnp.inf, dtype=jnp.float32)
+        fills = {
+            F_FIN: inf, F_SUB: inf, F_TGT: inf,
+            F_PART: jnp.full((R, N), -1.0, dtype=jnp.float32),
+            F_REM: jnp.ones((R, N), dtype=jnp.float32),
+        }
+        st0 = tuple(fills.get(f, zeros) for f in range(NFIELDS))
+        zf = partial(jnp.zeros, dtype=jnp.float32)
+        return loop(
+            st0, codes0, zf((R, P)), zf((R, S_)), zf((R, S_)),
+            zf((R,)), zf((R,)), zf((R,)),
+        )
+
+    return run
+
+
 def simulate(
     cfg: KernelConfig,
     const_np: Dict[str, np.ndarray],
@@ -871,52 +900,24 @@ def simulate(
 ) -> Dict[str, np.ndarray]:
     """Run the compiled round loop; returns final state as NumPy arrays.
 
-    ``const_np`` holds the host-precomputed statics (see
-    :func:`repro.core.sim.soa.build_problem`), ``lanes_np`` the per-lane
-    trace data (``work``, ``io``, ``codes0``).  The compiled loop is
-    cached on ``(cfg, const-content digest, lane shapes)`` — the const
-    arrays are closed over as compile-time constants, so the key must
-    carry their *values* (see :func:`_const_digest`); re-running the
-    same scenario cell with new seeds skips compilation entirely.
+    ``lanes_np`` holds the per-lane trace data (``work``, ``io``,
+    ``codes0``).  The compiled loop (:func:`round_loop`) is cached on
+    ``(cfg, const-content digest, lane shapes)`` — the const arrays are
+    closed over as compile-time constants, so the key must carry their
+    *values* (see :func:`_const_digest`); re-running the same scenario
+    cell with new seeds skips compilation entirely.
     """
-    if not HAS_JAX:  # pragma: no cover
-        raise RuntimeError("repro.core.sim.soa requires jax")
     R, N = lanes_np["work"].shape
     key = (
         cfg,
         _const_digest(const_np),
         (R, N, lanes_np["codes0"].shape[1]),
     )
-    cached = _LOOP_CACHE.get(key)
-    if cached is None:
-        const = {k: jnp.asarray(v) for k, v in const_np.items()}
-        S_ = int(const["caps"].shape[0])
-        P = cfg.P
+    loop = _LOOP_CACHE.get(key)
+    if loop is None:
+        loop = _LOOP_CACHE[key] = round_loop(cfg, const_np)
 
-        @jax.jit
-        def run(work, io, codes0):
-            cdev = dict(const)
-            cdev["work"] = work
-            cdev["io"] = io
-            loop = _build_loop(cfg, cdev)
-            zeros = jnp.zeros((R, N), dtype=jnp.float32)
-            inf = jnp.full((R, N), jnp.inf, dtype=jnp.float32)
-            fills = {
-                F_FIN: inf, F_SUB: inf, F_TGT: inf,
-                F_PART: jnp.full((R, N), -1.0, dtype=jnp.float32),
-                F_REM: jnp.ones((R, N), dtype=jnp.float32),
-            }
-            st0 = tuple(fills.get(f, zeros) for f in range(NFIELDS))
-            zf = partial(jnp.zeros, dtype=jnp.float32)
-            return loop(
-                st0, codes0, zf((R, P)), zf((R, S_)), zf((R, S_)),
-                zf((R,)), zf((R,)), zf((R,)),
-            )
-
-        cached = run
-        _LOOP_CACHE[key] = cached
-
-    st, codes, stall_end, busy, rel, nre, rbytes, dwork = cached(
+    st, codes, stall_end, busy, rel, nre, rbytes, dwork = loop(
         jnp.asarray(lanes_np["work"]),
         jnp.asarray(lanes_np["io"]),
         jnp.asarray(lanes_np["codes0"]),

@@ -140,14 +140,12 @@ class ScenarioSpec(ExperimentSpec):
 def soa_usable(spec: "ScenarioSpec") -> Tuple[bool, str]:
     """Whether the SoA jax backend can run ``spec`` (and why not).
 
-    The single place availability + per-spec support are decided: the
-    :func:`run` dispatcher, ``sweep``'s group runner, and the campaign
-    service all consult this instead of re-deriving the check.
+    The single place per-spec support is decided: the :func:`run`
+    dispatcher, ``sweep``'s group runner, and the campaign service all
+    consult this instead of re-deriving the check.
     """
     from ..core.sim import soa
 
-    if not soa.soa_available():
-        return False, "jax is not available"
     if not soa.soa_supported(
         spec.policy, spec.replan_mode, spec.detection_delay_s,
         spec.drop_policy, spec.record,
@@ -164,16 +162,6 @@ def soa_usable(spec: "ScenarioSpec") -> Tuple[bool, str]:
             "SoA kernels do not model)",
         )
     return True, ""
-
-
-def _soa_available() -> bool:
-    from ..core.sim import soa
-
-    return soa.soa_available()
-
-
-def _always_available() -> bool:
-    return True
 
 
 def _always_supported(_spec) -> Tuple[bool, str]:
@@ -197,8 +185,6 @@ class SweepBackend:
     #: runs many lanes in one call (seed fans / scenario groups)
     batched: bool
     description: str
-    #: process-wide availability (e.g. optional jax dependency)
-    is_available: Callable[[], bool] = _always_available
     #: per-spec support: ``(ok, reason_if_not)``
     supports: Callable[[object], Tuple[bool, str]] = _always_supported
 
@@ -264,7 +250,6 @@ SWEEP_BACKENDS = BackendRegistry(
             "structure-of-arrays jax backend; distributionally "
             "equivalent, profitable for many seeds of one cell"
         ),
-        is_available=_soa_available,
         supports=soa_usable,
     ),
 )
@@ -506,10 +491,9 @@ def _run_soa(
     seeds of one scenario cell, e.g. tail estimation; the jit compile
     is amortized across lanes but repaid on every new scenario shape).
 
-    Raises :class:`repro.core.sim.soa.SoaUnsupported` when jax is
-    missing or the spec needs features outside the kernel's support
-    set; :func:`run` consults :func:`soa_usable` first and owns the
-    fallback decision.
+    Raises :class:`repro.core.sim.soa.SoaUnsupported` when the spec
+    needs features outside the kernel's support set; :func:`run`
+    consults :func:`soa_usable` first and owns the fallback decision.
     """
     from ..core.sim import soa
 
@@ -602,7 +586,7 @@ def run(
       it must be asked for by name.
     * ``"scalar"`` / ``"lockstep"`` — force that exact-family engine.
     * ``"soa"`` — the distributional jax backend.  Specs it cannot run
-      (unavailable jax, unsupported feature, attached recorder) fall
+      (unsupported feature, attached recorder) fall
       back to an exact engine when ``fallback=True`` (the sweep
       default) or raise ``SoaUnsupported`` when ``fallback=False``.
 
@@ -872,7 +856,9 @@ def sweep(
     (reference engine), or ``"soa"`` (distributionally-equivalent jax
     backend; per-scenario jit compiles make it the validation shape
     here, not the throughput shape — use ``run(spec, seeds=...,
-    backend="soa")`` directly for many-seed cells).
+    backend="soa")`` directly for many-seed cells).  SoA groups run in
+    the calling process whatever ``jobs`` says: the accelerator belongs
+    to one process, and pool workers run on the CPU.
 
     ``cache_dir`` routes the sweep through the campaign service
     (:func:`repro.sweeps.run_campaign`): rows are stored
@@ -925,7 +911,8 @@ def sweep(
             group.append(dataclasses.replace(spec, portfolio=portfolios[pol]))
         groups.append(group)
     rows_per_group = parallel_map(
-        functools.partial(_run_group, backend=backend), groups, jobs
+        functools.partial(_run_group, backend=backend), groups,
+        1 if backend == "soa" else jobs,
     )
     return [row for rows in rows_per_group for row in rows]
 

@@ -15,9 +15,15 @@ from *how* to run it:
   cache.  On one host it is a process-isolation harness; pointed at a
   shared filesystem it is the multi-host shape (one invocation per
   host, ``--shard i --num-shards k``).
+
+Every process either executor starts runs with ``JAX_PLATFORMS=cpu``:
+an accelerator belongs to one process at a time, and the caller may
+already hold it.  Work that needs the chip (the SoA backend) runs in
+the calling process instead (see ``repro.sweeps.service``).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import multiprocessing
@@ -79,6 +85,26 @@ class _Capture:
             return ("err", payload, repr(exc), tb)
 
 
+#: environment of every worker process the executors start
+_WORKER_ENV = {"JAX_PLATFORMS": "cpu"}
+
+
+@contextlib.contextmanager
+def _worker_environ():
+    """Apply :data:`_WORKER_ENV` while worker processes are spawned
+    (they inherit the environment at exec, before any import)."""
+    saved = {k: os.environ.get(k) for k in _WORKER_ENV}
+    os.environ.update(_WORKER_ENV)
+    try:
+        yield
+    finally:
+        for k, v in saved.items():
+            if v is None:
+                os.environ.pop(k, None)
+            else:
+                os.environ[k] = v
+
+
 def _resolve_jobs(jobs: Optional[int], n_items: int) -> int:
     if jobs is None:
         jobs = os.cpu_count() or 1
@@ -114,7 +140,9 @@ class LocalPoolExecutor:
                 yield i, self._decode(i, capture(item))
             return
         ctx = multiprocessing.get_context("spawn")
-        with ctx.Pool(processes=jobs) as pool:
+        with _worker_environ():
+            pool = ctx.Pool(processes=jobs)
+        with pool:
             for i, tagged in enumerate(pool.imap(capture, items)):
                 yield i, self._decode(i, tagged)
 
@@ -200,7 +228,7 @@ class SubprocessShardExecutor:
                 ]
                 procs.append((shard, report, subprocess.Popen(
                     cmd, stdout=subprocess.DEVNULL, stderr=subprocess.PIPE,
-                    text=True,
+                    text=True, env={**os.environ, **_WORKER_ENV},
                 )))
             for shard, report, proc in procs:
                 _out, err = proc.communicate(timeout=timeout)

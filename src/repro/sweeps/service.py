@@ -347,7 +347,10 @@ def run_campaign(
 
     ``executor`` defaults to :class:`LocalPoolExecutor(jobs)`; pass a
     :class:`SubprocessShardExecutor` to fan the manifest out across
-    worker invocations (requires ``manifest_path``).  ``keep_rows=False``
+    worker invocations (requires ``manifest_path``).  Scenario groups
+    holding a SoA-class cell run in this process before the executor
+    starts (workers run on the CPU; the accelerator belongs to the
+    caller), so shard workers find them cached.  ``keep_rows=False``
     streams every row straight into the reducer and returns
     ``rows=None`` — the O(1)-memory shape for very large campaigns.
     """
@@ -391,7 +394,15 @@ def run_campaign(
         manifest.save(manifest_path)
 
     missing = [c for c in cells if records[c.index].status == "pending"]
+    on_device = {c.scenario_index for c in missing if c.backend_class == "soa"}
+    here = [c for c in missing if c.scenario_index in on_device]
+    missing = [c for c in missing if c.scenario_index not in on_device]
     n_executed = 0
+    if here:
+        n_executed += _execute_local(
+            LocalPoolExecutor(1), spec_obj, cache, here, records, rows,
+            reducer, keep_rows, manifest, manifest_path,
+        )
     if missing:
         if isinstance(executor, SubprocessShardExecutor):
             if manifest_path is None:
@@ -399,12 +410,12 @@ def run_campaign(
                     "SubprocessShardExecutor needs manifest_path (the "
                     "manifest is the work-distribution medium)"
                 )
-            n_executed = _execute_sharded(
+            n_executed += _execute_sharded(
                 executor, manifest, manifest_path, cache, missing,
                 records, rows, reducer, keep_rows,
             )
         else:
-            n_executed = _execute_local(
+            n_executed += _execute_local(
                 executor or LocalPoolExecutor(jobs), spec_obj, cache,
                 missing, records, rows, reducer, keep_rows,
                 manifest, manifest_path,

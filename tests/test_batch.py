@@ -94,22 +94,24 @@ def test_mixed_skeleton_batch_rejected():
 
 # ---------------------------------------------------------------------------
 # property test: random scenarios/workloads, scalar-vs-batched equality.
-@pytest.mark.skipif(not batch_mod._HAS_JAX, reason="jax not installed")
 def test_ndtri_jnp_matches_numpy_at_stream_boundaries():
     """The stream contract's uniforms are ``(m + 0.5) * 2**-53``; the
     top draw's real value ``1 - 2**-54`` rounds to exactly 1.0 in
     binary64, where the NumPy ``ndtri`` array path returns ``+inf`` —
     the device mirror must agree on every reachable input, boundary
     included (not clip it to a finite tail value)."""
+    import jax
+    import jax.numpy as jnp
+
     from repro.core.latency_model import ndtri
-    from repro.core.sim.batch import _enable_x64, _jnp, _ndtri_jnp
+    from repro.core.sim.batch import _ndtri_jnp
 
     top = (np.float64((1 << 53) - 1) + 0.5) * 2.0**-53
     assert top == 1.0  # the binary64 fact the boundary branch exists for
     bot = 0.5 * 2.0**-53  # the stream's smallest draw
     qs = np.array([bot, 1e-12, 0.02, 0.3, 0.99, 1.0 - 2.0**-52, top])
-    with _enable_x64():
-        got = np.asarray(_ndtri_jnp(_jnp.asarray(qs)))
+    with jax.enable_x64(True):
+        got = np.asarray(_ndtri_jnp(jnp.asarray(qs)))
     want = ndtri(qs)
     assert want[-1] == np.inf and got[-1] == np.inf
     np.testing.assert_allclose(got, want, rtol=1e-12, atol=1e-15)

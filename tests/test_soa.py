@@ -17,9 +17,10 @@ The full bundled-scenario sweep runs in CI as its own gate
 scenario pins the contract into tier-1 per policy, plus support
 predicates, the device sampling path, the allocator reference kernel,
 and a property test over random Markov scenarios mirroring
-``test_batch.py``.  Everything needing jax skips cleanly without it.
+``test_batch.py``.
 """
 import dataclasses
+import os
 
 import numpy as np
 import pytest
@@ -29,10 +30,6 @@ from repro.core.sim import soa_kernels as K
 from repro.core.sim.batch import sample_trace_batch
 from repro.scenarios.runner import ScenarioSpec, run
 from repro.scenarios.script import default_generator, get_scenario
-
-needs_jax = pytest.mark.skipif(
-    not soa.soa_available(), reason="jax not installed (SoA backend unavailable)"
-)
 
 SEEDS = [0, 1, 2, 3]
 
@@ -57,7 +54,6 @@ def _pooled_latencies(reports):
 # ---------------------------------------------------------------------------
 # equivalence contract, per policy
 # ---------------------------------------------------------------------------
-@needs_jax
 @pytest.mark.parametrize("policy", ["cyc", "tp_driven", "ads_tile"])
 def test_soa_distributionally_equivalent(policy):
     ref, got = _cell("commute", policy)
@@ -76,7 +72,6 @@ def test_soa_distributionally_equivalent(policy):
 # ---------------------------------------------------------------------------
 # compile-cache identity: same shapes, different schedule constants
 # ---------------------------------------------------------------------------
-@needs_jax
 def test_kernel_cache_distinguishes_const_content():
     """Two cells over the same skeleton (same array shapes) but with
     different schedule constants must not share a compiled loop: the
@@ -120,7 +115,6 @@ def test_kernel_cache_distinguishes_const_content():
 # ---------------------------------------------------------------------------
 # window-lifetime overflow: detect, refuse, retry wider
 # ---------------------------------------------------------------------------
-@needs_jax
 def test_window_overflow_detected_and_retried():
     """A job that slides out of the job window unresolved (overload
     queueing past the E2E-deadline lifetime bound under the soft drop
@@ -166,7 +160,7 @@ def test_window_overflow_detected_and_retried():
 
 
 # ---------------------------------------------------------------------------
-# support predicates + clean degradation without jax
+# support predicates + where the backend runs
 # ---------------------------------------------------------------------------
 def test_soa_supported_predicate():
     assert soa.soa_supported("cyc")
@@ -177,19 +171,26 @@ def test_soa_supported_predicate():
     assert not soa.soa_supported("cyc", record=True)
 
 
-def test_run_problem_raises_without_jax(monkeypatch):
-    """A jax-less platform degrades to a typed error, not an
-    ImportError from kernel internals."""
-    monkeypatch.setattr(K, "HAS_JAX", False)
-    assert not soa.soa_available()
-    with pytest.raises(soa.SoaUnsupported):
-        soa.run_problem(None, None, [0])
-    spec = ScenarioSpec(scenario=get_scenario("commute"), policy="cyc")
-    with pytest.raises(soa.SoaUnsupported):
-        run(spec, seeds=[0], backend="soa", fallback=False)
+def test_soa_sweep_runs_in_calling_process(monkeypatch):
+    """An accelerator belongs to one process: a SoA sweep asked for
+    two pool workers still runs every group here, where the caller's
+    device is, instead of in workers that run on the CPU."""
+    from repro.scenarios import runner, sweep
+
+    pids = []
+    real = runner._run_soa
+
+    def spy(spec, seeds, options=None):
+        pids.append(os.getpid())
+        return real(spec, seeds, options)
+
+    monkeypatch.setattr(runner, "_run_soa", spy)
+    rows = sweep(2, policies=("cyc",), duration_s=0.3, seed=4, jobs=2,
+                 backend="soa")
+    assert len(rows) == 2
+    assert pids == [os.getpid()] * 2
 
 
-@needs_jax
 def test_soa_backend_rejects_unsupported_spec():
     spec = ScenarioSpec(
         scenario=get_scenario("commute"), policy="cyc", replan_mode="predictive"
@@ -201,7 +202,6 @@ def test_soa_backend_rejects_unsupported_spec():
 # ---------------------------------------------------------------------------
 # device sampling path (stream contract on jnp)
 # ---------------------------------------------------------------------------
-@needs_jax
 def test_device_sampling_matches_numpy_path():
     spec = ScenarioSpec(scenario=get_scenario("commute"), policy="cyc")
     from repro.core.sim.trace import build_skeleton
@@ -221,7 +221,6 @@ def test_device_sampling_matches_numpy_path():
 # ---------------------------------------------------------------------------
 # allocator kernel vs the NumPy oracle
 # ---------------------------------------------------------------------------
-@needs_jax
 def test_ladder_grant_matches_reference():
     rng = np.random.default_rng(0)
     limit = rng.integers(0, 9, size=(5, 16)).astype(np.float32)
@@ -232,13 +231,33 @@ def test_ladder_grant_matches_reference():
 
     got = np.asarray(K._ladder_grant(jnp.asarray(limit), jnp.asarray(cand)))
     np.testing.assert_array_equal(want, got)
-    if K.HAS_PALLAS:
-        got_p = np.asarray(
-            K._ladder_grant_pallas(
-                jnp.asarray(limit), jnp.asarray(cand), interpret=True
-            )
+    got_p = np.asarray(
+        K._ladder_grant_pallas(
+            jnp.asarray(limit), jnp.asarray(cand), interpret=True
         )
-        np.testing.assert_array_equal(want, got_p)
+    )
+    np.testing.assert_array_equal(want, got_p)
+
+
+@pytest.mark.parametrize("per_lane", [False, True])
+def test_pallas_ladder_grant_blocks_match_jnp(per_lane):
+    """The Pallas grant walks lanes in blocks (the last one partial
+    here) with the ladder unrolled off the lane axis; it must equal the
+    jnp select bit for bit, for one shared ladder per job (the round
+    loop's case) and for per-lane ladders."""
+    import jax.numpy as jnp
+
+    R, W, C = K._GRANT_BLOCK_R + 24, 80, 6
+    rng = np.random.default_rng(1)
+    limit = rng.integers(-1, 40, size=(R, W)).astype(np.float32)
+    shape = (R, W, C) if per_lane else (W, C)
+    cand = np.sort(rng.integers(1, 33, size=shape), axis=-1).astype(np.float32)
+    want = K.ladder_grant_reference(limit, cand)
+    got_j = np.asarray(K._ladder_grant(jnp.asarray(limit), jnp.asarray(cand)))
+    got_p = np.asarray(K._ladder_grant_pallas(
+        jnp.asarray(limit), jnp.asarray(cand), interpret=True))
+    np.testing.assert_array_equal(want, got_j)
+    np.testing.assert_array_equal(want, got_p)
 
 
 # ---------------------------------------------------------------------------
@@ -254,7 +273,6 @@ except ImportError:
 
 else:
 
-    @needs_jax
     @given(
         gen_seed=st.integers(0, 1_000),
         run_seed=st.integers(0, 10_000),

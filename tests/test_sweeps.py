@@ -17,6 +17,7 @@ Contracts under test (docs/sweeps.md):
 """
 import dataclasses
 import json
+import os
 
 import pytest
 
@@ -42,6 +43,7 @@ from repro.sweeps import (
     cell_key,
     run_campaign,
 )
+from repro.sweeps import executor as executor_mod
 from repro.sweeps.manifest import CampaignManifest, CellRecord
 from repro.sweeps.worker import run_shard
 
@@ -331,3 +333,63 @@ def test_parallel_map_reraises_after_full_pass():
     with pytest.raises(ValueError, match="boom on 2"):
         parallel_map(_boom, [1, 2, 3], jobs=1)
     assert parallel_map(_square, [1, 2, 3], jobs=1) == [1, 4, 9]
+
+
+# ---------------------------------------------------------------------------
+# worker processes stay off the accelerator
+# ---------------------------------------------------------------------------
+def _worker_env(_):
+    return os.getpid(), os.environ.get("JAX_PLATFORMS")
+
+
+def test_pool_workers_run_on_cpu(monkeypatch):
+    """A chip belongs to one process, and the caller may hold it: pool
+    workers start with JAX_PLATFORMS=cpu whatever the caller's
+    environment says, and the caller's environment is left as it was."""
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    out = executor_mod.LocalPoolExecutor(2).map(_worker_env, [0, 1])
+    assert all(pid != os.getpid() for pid, _p in out)
+    assert [plat for _pid, plat in out] == ["cpu", "cpu"]
+    assert "JAX_PLATFORMS" not in os.environ
+
+
+def test_soa_campaign_runs_in_calling_process(monkeypatch, tmp_path):
+    """SoA cells need the caller's accelerator: a campaign asked for
+    two pool workers runs them here, not in CPU-only workers."""
+    from repro.scenarios import runner
+
+    pids = []
+    real = runner._run_soa
+
+    def spy(spec, seeds, options=None):
+        pids.append(os.getpid())
+        return real(spec, seeds, options)
+
+    monkeypatch.setattr(runner, "_run_soa", spy)
+    spec = CampaignSpec(**{**CAMPAIGN_KW, "policies": ("cyc",),
+                           "backend": "soa"})
+    res = run_campaign(spec, cache_dir=tmp_path / "c", jobs=2)
+    assert res.n_executed == 2
+    assert pids == [os.getpid()] * 2
+
+
+def test_shard_workers_run_on_cpu(monkeypatch, tmp_path):
+    seen = []
+
+    class _Proc:
+        returncode = 0
+
+        def communicate(self, timeout=None):
+            return "", ""
+
+    def fake_popen(cmd, **kw):
+        seen.append(kw["env"])
+        return _Proc()
+
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    monkeypatch.setattr(executor_mod.subprocess, "Popen", fake_popen)
+    executor_mod.SubprocessShardExecutor(num_shards=2).run_manifest(
+        tmp_path / "m.json", tmp_path / "cache"
+    )
+    assert [env["JAX_PLATFORMS"] for env in seen] == ["cpu", "cpu"]
+    assert os.environ["JAX_PLATFORMS"] == "tpu"
