@@ -1,0 +1,19 @@
+"""round.step.device_us_per_round: device microseconds per round in the round loop's seam, finishes, readiness, drops.
+
+From the profiler trace of one whole warm call: the device time of the
+leaf operations of the round-loop executable whose name stack carries
+the body scope ``step`` (the seam hot-swap ``lax.cond``, finishes, readiness, deadline drops, finish codes and accounting;
+``soa_kernels._build_loop``), over the rounds that the call's
+round-loop attempts ran.  Reduced by ``harness.program``; absent where
+no operation of the trace carries the scope.
+"""
+from harness import program
+
+UNIT = "us/round"
+HOOKS = {}
+SCOPE = "step"
+program.install()
+
+
+def read(ctx):
+    return program.READER.scope_us_per_round(ctx, SCOPE)

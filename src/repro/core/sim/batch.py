@@ -257,16 +257,21 @@ def _sample_trace_batch_jnp(skel, par, seeds):
     sensor_lat = np.zeros((B, n), dtype=np.float64)
     d, s = skel.dnn_ix, skel.sen_ix
     with jax.enable_x64(True):
-        w, i, lat = _device_draws(
-            _seed_keys(seeds, STREAM_WORK),
-            _seed_keys(seeds, STREAM_IO),
-            _seed_keys(seeds, STREAM_SENSOR),
-            _device_jobs(skel, par, d),
-            _device_jobs(skel, par, s),
-        )
-        work[:, d] = np.asarray(w)
-        io[:, d] = np.asarray(i)
-        sensor_lat[:, s] = np.asarray(lat)
+        with metrics.phase("trace_sample_draws"):
+            draws = _device_draws(
+                _seed_keys(seeds, STREAM_WORK),
+                _seed_keys(seeds, STREAM_IO),
+                _seed_keys(seeds, STREAM_SENSOR),
+                _device_jobs(skel, par, d),
+                _device_jobs(skel, par, s),
+            )
+            if metrics.enabled():
+                jax.block_until_ready(draws)
+        with metrics.phase("trace_sample_fetch"):
+            w, i, lat = (np.asarray(x) for x in draws)
+        work[:, d] = w
+        io[:, d] = i
+        sensor_lat[:, s] = lat
     return work, io, sensor_lat
 
 

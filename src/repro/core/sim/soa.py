@@ -35,6 +35,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ...obs import metrics
 from .engine import ModeStats, SimReport
 from .trace import build_skeleton
 from . import soa_kernels as K
@@ -623,7 +624,9 @@ def run_problem(
         raise ValueError(
             f"problem compiled for R={problem.cfg.R}, got {len(seeds)} seeds"
         )
-    out = K.simulate(problem.cfg, problem.const, _lanes(problem, btrace))
+    with metrics.phase("soa_lanes"):
+        lanes = _lanes(problem, btrace)
+    out = K.simulate(problem.cfg, problem.const, lanes)
     # jobs below the final window lower bound had their window close
     # before the horizon end; any still unresolved there froze mid-queue
     # (overload past the lifetime bound) and the lane's report would
@@ -634,6 +637,8 @@ def run_problem(
         if np.any(stuck):
             n_lanes = int(np.sum(np.any(stuck, axis=1)))
             n_jobs = int(np.max(np.sum(stuck, axis=1)))
+            metrics.count("soa_window_overflows")
+            metrics.count("soa_overflow_lanes", n_lanes)
             raise SoaWindowOverflow(
                 f"up to {n_jobs} job(s) per lane slid out of the "
                 f"{problem.life:.3f}s SoA job window unresolved "
@@ -644,10 +649,23 @@ def run_problem(
                 "doubled window automatically) or use the scalar/"
                 "lockstep backend for this cell."
             )
-    return _assemble_reports(problem, out)
+    with metrics.phase("soa_assemble"):
+        return _assemble_reports(problem, out)
 
 
 def _assemble_reports(problem: SoaProblem, out: Dict[str, np.ndarray]):
+    """One report per lane: the whole-array part, then each lane's."""
+    with metrics.phase("soa_assemble_arrays"):
+        arrays = _report_arrays(problem, out)
+    with metrics.phase("soa_assemble_lanes"):
+        return [
+            _lane_report(problem, out, arrays, k) for k in range(problem.cfg.R)
+        ]
+
+
+def _report_arrays(problem: SoaProblem, out: Dict[str, np.ndarray]) -> dict:
+    """Every lane's outcome per sink, and the tile-second totals, as
+    whole arrays."""
     R = problem.cfg.R
     dur = problem.duration
     total = problem.num_tiles * dur
@@ -686,105 +704,117 @@ def _assemble_reports(problem: SoaProblem, out: Dict[str, np.ndarray]):
         mode_busy[m] = mode_busy.get(m, 0.0) + busy_seg[:, s]
         mode_rel[m] = mode_rel.get(m, 0.0) + rel_seg[:, s]
 
-    reports: List[SimReport] = []
-    for k in range(R):
-        chain_count = {c: 0 for c in problem.chain_names}
-        chain_viol = {c: 0 for c in problem.chain_names}
-        chain_lats: Dict[str, List[float]] = {c: [] for c in problem.chain_names}
-        sink_by_mode: Dict[Tuple[str, str], List[int]] = {}
-        mode_lats: Dict[str, List[float]] = {}
-        for i, (cname, _p, t0, _ddl, m) in enumerate(problem.sinks):
-            if done_s[k, i]:
-                chain_count[cname] += 1
-                chain_viol[cname] += int(viol_s[k, i])
-                chain_lats[cname].append(float(lat_s[k, i]))
-                rec = sink_by_mode.setdefault((cname, m), [0, 0])
-                rec[0] += 1
-                rec[1] += int(viol_s[k, i])
-                mode_lats.setdefault(m, []).append(float(lat_s[k, i]))
-            elif drop_s[k, i]:
-                chain_count[cname] += 1
-                chain_viol[cname] += 1
-                rec = sink_by_mode.setdefault((cname, m), [0, 0])
-                rec[0] += 1
-                rec[1] += 1
+    return {
+        "total": total, "n_jobs": n_jobs, "n_dropped": n_dropped,
+        "n_miss": n_miss, "lat_s": lat_s, "done_s": done_s,
+        "drop_s": drop_s, "viol_s": viol_s, "mode_busy": mode_busy,
+        "mode_rel": mode_rel, "busy_tot": busy_tot, "rel_tot": rel_tot,
+    }
 
-        # starvation deficits, reconciled chronologically per mode
-        for cname in problem.chain_names:
-            deficit = max(0, problem.expected[cname] - chain_count[cname])
-            if not deficit:
+
+def _lane_report(
+    problem: SoaProblem, out: Dict[str, np.ndarray], a: dict, k: int
+) -> SimReport:
+    """Lane ``k``'s report from :func:`_report_arrays`."""
+    dur, total, n_jobs = problem.duration, a["total"], a["n_jobs"]
+    done_s, drop_s, viol_s, lat_s = a["done_s"], a["drop_s"], a["viol_s"], a["lat_s"]
+    mode_busy, mode_rel = a["mode_busy"], a["mode_rel"]
+    chain_count = {c: 0 for c in problem.chain_names}
+    chain_viol = {c: 0 for c in problem.chain_names}
+    chain_lats: Dict[str, List[float]] = {c: [] for c in problem.chain_names}
+    sink_by_mode: Dict[Tuple[str, str], List[int]] = {}
+    mode_lats: Dict[str, List[float]] = {}
+    for i, (cname, _p, t0, _ddl, m) in enumerate(problem.sinks):
+        if done_s[k, i]:
+            chain_count[cname] += 1
+            chain_viol[cname] += int(viol_s[k, i])
+            chain_lats[cname].append(float(lat_s[k, i]))
+            rec = sink_by_mode.setdefault((cname, m), [0, 0])
+            rec[0] += 1
+            rec[1] += int(viol_s[k, i])
+            mode_lats.setdefault(m, []).append(float(lat_s[k, i]))
+        elif drop_s[k, i]:
+            chain_count[cname] += 1
+            chain_viol[cname] += 1
+            rec = sink_by_mode.setdefault((cname, m), [0, 0])
+            rec[0] += 1
+            rec[1] += 1
+
+    # starvation deficits, reconciled chronologically per mode
+    for cname in problem.chain_names:
+        deficit = max(0, problem.expected[cname] - chain_count[cname])
+        if not deficit:
+            continue
+        chain_viol[cname] += deficit
+        chain_count[cname] = problem.expected[cname]
+        em = problem.expected_mode[cname]
+        for m in problem.mode_order:
+            if m not in em:
                 continue
-            chain_viol[cname] += deficit
-            chain_count[cname] = problem.expected[cname]
-            em = problem.expected_mode[cname]
-            for m in problem.mode_order:
-                if m not in em:
-                    continue
-                rec = sink_by_mode.setdefault((cname, m), [0, 0])
-                take = min(max(0, em[m] - rec[0]), deficit)
-                if take:
-                    rec[0] += take
-                    rec[1] += take
-                    deficit -= take
-                if not deficit:
-                    break
+            rec = sink_by_mode.setdefault((cname, m), [0, 0])
+            take = min(max(0, em[m] - rec[0]), deficit)
+            if take:
+                rec[0] += take
+                rec[1] += take
+                deficit -= take
+            if not deficit:
+                break
 
-        p99 = {
-            c: (float(np.percentile(ls, 99)) if ls else float("nan"))
-            for c, ls in chain_lats.items()
-        }
-        mode_stats: Dict[str, ModeStats] = {}
-        for m, span in problem.spans.items():
-            done_m = sum(
-                rec[0] for (_c, mm), rec in sink_by_mode.items() if mm == m
-            )
-            viol_m = sum(
-                rec[1] for (_c, mm), rec in sink_by_mode.items() if mm == m
-            )
-            lats = mode_lats.get(m, [])
-            denom = problem.num_tiles * span
-            mb = float(np.asarray(mode_busy.get(m, 0.0))[k]) if m in mode_busy else 0.0
-            mr = float(np.asarray(mode_rel.get(m, 0.0))[k]) if m in mode_rel else 0.0
-            mode_stats[m] = ModeStats(
-                mode=m,
-                span_s=span,
-                n_completed=done_m,
-                n_violations=viol_m,
-                p99_s=(
-                    float(np.percentile(np.asarray(lats), 99))
-                    if lats else float("nan")
-                ),
-                effective_frac=mb / denom if denom > 0 else 0.0,
-                realloc_frac=mr / denom if denom > 0 else 0.0,
-            )
+    p99 = {
+        c: (float(np.percentile(ls, 99)) if ls else float("nan"))
+        for c, ls in chain_lats.items()
+    }
+    mode_stats: Dict[str, ModeStats] = {}
+    for m, span in problem.spans.items():
+        done_m = sum(
+            rec[0] for (_c, mm), rec in sink_by_mode.items() if mm == m
+        )
+        viol_m = sum(
+            rec[1] for (_c, mm), rec in sink_by_mode.items() if mm == m
+        )
+        lats = mode_lats.get(m, [])
+        denom = problem.num_tiles * span
+        mb = float(np.asarray(mode_busy.get(m, 0.0))[k]) if m in mode_busy else 0.0
+        mr = float(np.asarray(mode_rel.get(m, 0.0))[k]) if m in mode_rel else 0.0
+        mode_stats[m] = ModeStats(
+            mode=m,
+            span_s=span,
+            n_completed=done_m,
+            n_violations=viol_m,
+            p99_s=(
+                float(np.percentile(np.asarray(lats), 99))
+                if lats else float("nan")
+            ),
+            effective_frac=mb / denom if denom > 0 else 0.0,
+            realloc_frac=mr / denom if denom > 0 else 0.0,
+        )
 
-        busy = float(busy_tot[k])
-        rel_ts = float(rel_tot[k])
-        reports.append(SimReport(
-            duration_s=dur,
-            total_tiles=problem.num_tiles,
-            effective_frac=busy / total,
-            realloc_frac=rel_ts / total,
-            idle_frac=max(0.0, 1.0 - (busy + rel_ts) / total),
-            dropped_work_frac=float(out["dropped_work"][k]) / total,
-            n_realloc=int(round(float(out["n_realloc"][k]))),
-            realloc_bytes=float(out["realloc_bytes"][k]),
-            n_jobs=n_jobs,
-            n_dropped=int(n_dropped[k]),
-            task_miss_rate=float(n_miss[k]) / max(n_jobs, 1),
-            chain_count=chain_count,
-            chain_violations=chain_viol,
-            chain_p99_s=p99,
-            chain_latencies=chain_lats,
-            decision_ratios=[],
-            mode_stats=mode_stats,
-            n_mode_switches=problem.n_mode_switches,
-            forecast=None,
-            tiles_used=problem.tiles_used,
-            tiles_reserved_mean=problem.tiles_reserved_mean,
-            frontier_meta=dict(problem.frontier_meta),
-        ))
-    return reports
+    busy = float(a["busy_tot"][k])
+    rel_ts = float(a["rel_tot"][k])
+    return SimReport(
+        duration_s=dur,
+        total_tiles=problem.num_tiles,
+        effective_frac=busy / total,
+        realloc_frac=rel_ts / total,
+        idle_frac=max(0.0, 1.0 - (busy + rel_ts) / total),
+        dropped_work_frac=float(out["dropped_work"][k]) / total,
+        n_realloc=int(round(float(out["n_realloc"][k]))),
+        realloc_bytes=float(out["realloc_bytes"][k]),
+        n_jobs=n_jobs,
+        n_dropped=int(a["n_dropped"][k]),
+        task_miss_rate=float(a["n_miss"][k]) / max(n_jobs, 1),
+        chain_count=chain_count,
+        chain_violations=chain_viol,
+        chain_p99_s=p99,
+        chain_latencies=chain_lats,
+        decision_ratios=[],
+        mode_stats=mode_stats,
+        n_mode_switches=problem.n_mode_switches,
+        forecast=None,
+        tiles_used=problem.tiles_used,
+        tiles_reserved_mean=problem.tiles_reserved_mean,
+        frontier_meta=dict(problem.frontier_meta),
+    )
 
 
 # ---------------------------------------------------------------------------

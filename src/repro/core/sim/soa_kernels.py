@@ -47,6 +47,8 @@ import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 
+from ...obs import metrics
+
 __all__ = [
     "KernelConfig",
     "NFIELDS",
@@ -392,425 +394,432 @@ def _build_loop(cfg: KernelConfig, const: Dict[str, "jnp.ndarray"]):
 
     def body(r, carry):
         st, codes, stall_end, busy, rel, nre, rbytes, dwork = carry
-        t0 = const["t0"][r]
-        t1 = const["t1"][r]
-        sg = const["seg"][r]
-        lo = const["lo"][r]
+        with jax.named_scope("window"):
+            t0 = const["t0"][r]
+            t1 = const["t1"][r]
+            sg = const["seg"][r]
+            lo = const["lo"][r]
 
-        # ``st`` is a tuple of NFIELDS separate (R, N) planes: updating
-        # a (R, W) window of each is in-place under the fori_loop,
-        # whereas a packed (R, N, NFIELDS) array made XLA:CPU copy the
-        # whole state every round (~7x the slice cost)
-        (state, ready_t, deg, start, fin, dop, pborn, rem, subb, tgtb,
-         adv) = (
-            lax.dynamic_slice(a, (0, lo), (R, W)) for a in st
-        )
-
-        relw = lax.dynamic_slice(const["release"], (lo,), (W,))
-        e2ew = lax.dynamic_slice(const["e2e"], (lo,), (W,))
-        syncw = lax.dynamic_slice(const["sync"], (lo,), (W,))
-        ckptw = lax.dynamic_slice(const["ckpt"], (lo,), (W,))
-        predw = lax.dynamic_slice(const["preds"], (lo, 0), (W, PM))
-        workw = lax.dynamic_slice(const["work"], (0, lo), (R, W))
-        iow = lax.dynamic_slice(const["io"], (0, lo), (R, W))
-        ertw = lax.dynamic_slice(const["ert"], (sg, lo), (1, W))[0]
-        subw = lax.dynamic_slice(const["sub"], (sg, lo), (1, W))[0]
-        tgtw = lax.dynamic_slice(const["tgt"], (sg, lo), (1, W))[0]
-        pdw = lax.dynamic_slice(const["pdop"], (sg, lo), (1, W))[0]
-        parw = lax.dynamic_slice(const["part"], (sg, lo), (1, W))[0]
-        candw = lax.dynamic_slice(const["cands"], (sg, lo, 0), (1, W, C))[0]
-        capsg = lax.dynamic_slice(const["caps"], (sg, 0), (1, P))[0]
-        hopsg = lax.dynamic_slice(const["hops"], (sg, 0), (1, P))[0]
-        stagedg = lax.dynamic_slice(const["staged"], (sg, 0), (1, P))[0]
-        permr = const["perm"][r]
-        ipermr = const["iperm"][r]
-
-        d_cur = dur(workw, iow, syncw, dop)
-
-        # ---- seam hot-swap (rare; only at segment-entry rounds) ------
-        do_swap = const["entry"][r] & const["swap"][sg]
-        state, fin, dop, rem, adv, stall_end, nre, rbytes = lax.cond(
-            do_swap,
-            seam_step,
-            lambda op: (op[0], op[1], op[2], op[3], op[4], op[6], op[7], op[8]),
-            (state, fin, dop, rem, adv, pborn, stall_end, nre, rbytes,
-             t0, workw, iow, syncw, ckptw, capsg, hopsg, stagedg),
-        )
-        d_cur = dur(workw, iow, syncw, dop)
-
-        # ---- finishes ------------------------------------------------
-        # drop_mode 1: cyc's unconditional budget enforcement at the
-        # bound sub-deadline; drop_mode 2: hard e2e-deadline dequeue;
-        # drop_mode 0 (the runner's soft default): late jobs finish late
-        run = state == RUN
-        if cfg.drop_mode == 1:
-            lim_run = subb
-        elif cfg.drop_mode == 2:
-            lim_run = jnp.broadcast_to(e2ew[None, :], (R, W))
-        else:
-            lim_run = jnp.full((R, W), jnp.inf, dtype=jnp.float32)
-        drop_run = run & (lim_run <= t1) & (fin > lim_run + 1e-9)
-        done_now = run & (fin <= t1) & ~drop_run
-        state = jnp.where(done_now, DONE, state)
-
-        # ---- readiness (release passed + all predecessors resolved) --
-        pend = state == PEND
-        pcodes = codes[:, predw.reshape(-1)].reshape(R, W, PM)
-        unresolved = jnp.any(jnp.isinf(pcodes), axis=-1)
-        rtimes = jnp.where(pcodes < 0, -pcodes - 1.0, pcodes)
-        res_t = jnp.maximum(relw[None, :], jnp.max(rtimes, axis=-1))
-        newready = pend & (relw[None, :] <= t1) & ~unresolved
-        state = jnp.where(newready, READY, state)
-        ready_t = jnp.where(newready, res_t, ready_t)
-        deg = jnp.where(newready, jnp.any(pcodes < -0.5, axis=-1), deg)
-
-        # ---- deadline drops (exact drop times, backdated) ------------
-        if cfg.drop_mode == 1:
-            lim_rdy = jnp.broadcast_to(subw[None, :], (R, W))
-        elif cfg.drop_mode == 2:
-            lim_rdy = jnp.broadcast_to(e2ew[None, :], (R, W))
-        else:
-            lim_rdy = jnp.full((R, W), jnp.inf, dtype=jnp.float32)
-        rdy = state == READY
-        drop_rdy = rdy & (lim_rdy <= t1)
-        droptime = jnp.where(
-            drop_run, lim_run, jnp.maximum(lim_rdy, ready_t)
-        )
-        dropping = drop_run | drop_rdy
-        rem_d = jnp.where(
-            drop_run,
-            jnp.clip((fin - droptime) / jnp.maximum(d_cur, 1e-12), 0.0, 1.0),
-            rem,
-        )
-        d_plan = dur(workw, iow, syncw, pdw[None, :])
-        dwork = dwork + jnp.sum(
-            jnp.where(dropping, rem_d * d_plan * pdw[None, :], 0.0), axis=1
-        )
-        state = jnp.where(dropping, DROP, state)
-        fin = jnp.where(dropping, droptime, fin)
-        deg = jnp.where(dropping, 1.0, deg)
-
-        # in-round capacity-release times per partition: a job that sat
-        # queued through earlier rounds can only start at the event that
-        # made room (a completion or drop), never back at its admission
-        # time — the scalar starts it from that event's callback
-        fpart = jnp.where(drop_rdy, parw[None, :], pborn).astype(jnp.int32)
-        freeing = done_now | dropping
-        ar_p = jnp.arange(P, dtype=jnp.int32)
-        freed_t_p = jnp.max(
-            jnp.where(
-                freeing[..., None] & (fpart[..., None] == ar_p),
-                fin[..., None], t0,
-            ),
-            axis=1,
-        )
-
-        # ---- finish codes (idempotent re-derivation for the window) --
-        terminal = state >= DONE
-        code_w = jnp.where(
-            terminal, jnp.where(deg > 0.5, -fin - 1.0, fin), jnp.inf
-        )
-        codes = lax.dynamic_update_slice(codes, code_w, (0, lo))
-
-        # ---- accounting: tile presence of the pre-policy state -------
-        run = state == RUN
-        alloc_p = jnp.sum(
-            jnp.where(
-                run[..., None] & (pborn.astype(jnp.int32)[..., None] == ar_p),
-                dop[..., None], 0.0,
-            ),
-            axis=1,
-        )
-        presence = jnp.where(
-            state >= RUN,
-            dop * jnp.clip(jnp.minimum(fin, t1) - jnp.maximum(start, t0), 0.0, None),
-            0.0,
-        ).sum(axis=1)
-        ov_p = jnp.clip(jnp.minimum(stall_end, t1) - t0, 0.0, None)
-        realloc_r = jnp.sum(alloc_p * ov_p, axis=1)
-
-        # ---- policy pass ---------------------------------------------
-        parw_i = parw.astype(jnp.int32)
-        stall_rdy = stall_end[:, jnp.clip(parw_i, 0, P - 1)]
-        adm = jnp.maximum(ready_t, stall_rdy)
-        if pol == _CYC or (pol == _ADS and cfg.admission):
-            adm = jnp.maximum(adm, ertw[None, :])
-        can = (state == READY) & (adm <= t1 + 1e-12)
-        own_freed = freed_t_p[:, jnp.clip(parw_i, 0, P - 1)]
-
-        free_p = capsg[None, :] - alloc_p
-        stalled_p = stall_end > t1
-
-        d_lad = (
-            workw[..., None] / (jnp.maximum(candw, 1.0)[None, :, :] * tf)
-            + iow[..., None]
-            + syncw[None, :, None] * jnp.maximum(candw - 1.0, 0.0)[None, :, :]
-        )
-
-        def want_of(rem_f, slack):
-            """fit_quota's ladder target with no tile cap (cap folds in
-            at grant time): smallest candidate meeting the deadline,
-            else the largest rung."""
-            if not cfg.quota_control:
-                return jnp.broadcast_to(candw[None, :, -1], (R, W))
-            meet = rem_f[..., None] * d_lad <= slack[..., None] + 1e-12
-            first = jnp.argmax(meet, axis=-1)
-            anym = jnp.any(meet, axis=-1)
-            cw = jnp.broadcast_to(candw[None, :, :], (R, W, C))
-            picked = jnp.take_along_axis(cw, first[..., None], axis=-1)[..., 0]
-            return jnp.where(anym, picked, candw[None, :, -1])
-
-        def edf_alloc(want_m, entry_m, part_m, cand_rows, pool, bump=False):
-            """EDF-permute, ladder-allocate, inverse-permute."""
-            want_s = jnp.take(want_m, permr, axis=1)
-            entry_s = jnp.take(entry_m, permr, axis=1)
-            part_s = jnp.take(part_m, permr, axis=1)
-            cand_s = (
-                jnp.take(cand_rows, permr, axis=0)
-                if cand_rows.ndim == 2
-                else cand_rows
-            )
-            grant_s = _alloc_ladder(cfg, want_s, entry_s, part_s, cand_s, pool)
-            if bump:
-                grant_s = _bump_work_conserving(
-                    cfg, grant_s, entry_s, part_s, cand_s, pool
-                )
-            return jnp.take(grant_s, ipermr, axis=1)
-
-        def per_part(mask, val=None):
-            """(R, P) per-partition sum (or any) keyed by an id array."""
-            m, ids = mask
-            oh = jnp.broadcast_to(ids, (R, W))[..., None] == ar_p
-            if val is None:
-                return jnp.any(m[..., None] & oh, axis=1)
-            v = jnp.broadcast_to(val, (R, W))
-            return jnp.sum(
-                jnp.where(m[..., None] & oh, v[..., None], 0.0), axis=1
+            # ``st`` is a tuple of NFIELDS separate (R, N) planes: updating
+            # a (R, W) window of each is in-place under the fori_loop,
+            # whereas a packed (R, N, NFIELDS) array made XLA:CPU copy the
+            # whole state every round (~7x the slice cost)
+            (state, ready_t, deg, start, fin, dop, pborn, rem, subb, tgtb,
+             adv) = (
+                lax.dynamic_slice(a, (0, lo), (R, W)) for a in st
             )
 
-        def own_of(arr_p, idx_i, padval):
-            pad = jnp.full((R, 1), padval, dtype=arr_p.dtype)
-            return jnp.take_along_axis(
-                jnp.concatenate([arr_p, pad], axis=1),
-                jnp.clip(idx_i, 0, P), axis=1,
-            )
+            relw = lax.dynamic_slice(const["release"], (lo,), (W,))
+            e2ew = lax.dynamic_slice(const["e2e"], (lo,), (W,))
+            syncw = lax.dynamic_slice(const["sync"], (lo,), (W,))
+            ckptw = lax.dynamic_slice(const["ckpt"], (lo,), (W,))
+            predw = lax.dynamic_slice(const["preds"], (lo, 0), (W, PM))
+            workw = lax.dynamic_slice(const["work"], (0, lo), (R, W))
+            iow = lax.dynamic_slice(const["io"], (0, lo), (R, W))
+            ertw = lax.dynamic_slice(const["ert"], (sg, lo), (1, W))[0]
+            subw = lax.dynamic_slice(const["sub"], (sg, lo), (1, W))[0]
+            tgtw = lax.dynamic_slice(const["tgt"], (sg, lo), (1, W))[0]
+            pdw = lax.dynamic_slice(const["pdop"], (sg, lo), (1, W))[0]
+            parw = lax.dynamic_slice(const["part"], (sg, lo), (1, W))[0]
+            candw = lax.dynamic_slice(const["cands"], (sg, lo, 0), (1, W, C))[0]
+            capsg = lax.dynamic_slice(const["caps"], (sg, 0), (1, P))[0]
+            hopsg = lax.dynamic_slice(const["hops"], (sg, 0), (1, P))[0]
+            stagedg = lax.dynamic_slice(const["staged"], (sg, 0), (1, P))[0]
+            permr = const["perm"][r]
+            ipermr = const["iperm"][r]
 
-        cap_pool = jnp.broadcast_to(capsg, (R, P))
-        if pol in (_CYC, _CYC_S):
-            # runners keep their tiles until they finish: ready jobs bid
-            # on *free* capacity only (under overload the planned slots
-            # collide and instances queue exactly like the scalar)
-            want = jnp.where(can, pdw[None, :], 0.0)
-            grant = edf_alloc(
-                want, can, jnp.broadcast_to(parw[None, :], (R, W)),
-                pdw[:, None], free_p,
-            )
-            started = can & (grant > 0.5)
-        elif pol == _TP:
-            # tp re-walks ready+running EDF against the *full* capacity
-            # on every queue change; between rounds the fixed point of
-            # quota+bump is stationary, so recomputing it each round
-            # reproduces the event-driven walk as long as the allocator
-            # reaches the same fixed point (alloc_iters / bump_passes)
-            slack_rdy = jnp.broadcast_to(subw[None, :], (R, W)) - jnp.maximum(adm, t0)
-            want_rdy = jnp.where(can, want_of(rem, slack_rdy), 0.0)
-            rem_run = jnp.clip(
-                (fin - t1) / jnp.maximum(d_cur, 1e-12), 0.0, 1.0
-            )
-            want_run_q = want_of(rem_run, subb - t1)
-            own_stalled = own_of(
-                stalled_p, pborn.astype(jnp.int32), True
-            )
-            want_run = jnp.where(own_stalled, dop, want_run_q)
-            want = jnp.where(run, want_run, want_rdy)
-            grant = edf_alloc(
-                want, can | run, jnp.where(run, pborn, parw[None, :]),
-                candw, cap_pool, bump=True,
-            )
-            started = can & (grant > 0.5)
-        else:
-            # ---- ads Algorithm 2, mirrored in two phases --------------
-            # Phase A (fast path): ready jobs start on *free* tiles at
-            # their quota while running jobs hold their allocation —
-            # under pressure this yields the scalar engine's best-effort
-            # small starts (fit_quota degrades to the largest rung that
-            # fits free), which is what later makes them at-risk and
-            # drives the grow cascade.
-            pborn_i = pborn.astype(jnp.int32)
-            cmaxw = candw[:, -1]
-            slack_rdy = jnp.broadcast_to(tgtw[None, :], (R, W)) - jnp.maximum(adm, t0)
-            want_rdy = jnp.where(can, want_of(rem, slack_rdy), 0.0)
-            partA = jnp.broadcast_to(parw[None, :], (R, W))
-            grantA = edf_alloc(want_rdy, can, partA, candw, free_p)
-            started1 = can & (grantA > 0.5)
+            d_cur = dur(workw, iow, syncw, dop)
 
-            # ChkTrigger on the post-fast-path state; the running set is
-            # the pre-start snapshot, as in the scalar policy.
-            alloc2 = alloc_p + per_part((started1, parw_i[None, :]), grantA)
-            free2 = cap_pool - alloc2
-            still = can & ~started1
-            own_free2 = free2[:, jnp.clip(parw_i, 0, P - 1)]
-            blocked = still & (want_rdy > own_free2 + 0.5)
-            # The scalar engine syncs ``job.progress`` only at the job's
-            # chunk boundaries (n_chunks per duration) and at realloc
-            # freezes, so its projection ``now + remaining`` runs on
-            # progress stale by up to one chunk interval — a job started
-            # with a thin margin drifts into at-risk between chunk
-            # syncs even though it is on track.  ``adv`` anchors the
-            # chunk grid (start / freeze end); the staleness at t1 is
-            # the time since the last chunk boundary before t1.
-            chunk_iv = jnp.maximum(d_cur, 1e-12) / jnp.float32(cfg.n_chunks)
-            stale_amt = jnp.where(
-                run,
-                jnp.mod(jnp.clip(t1 - adv, 0.0, None), chunk_iv),
-                0.0,
+        with jax.named_scope("step"):
+            # ---- seam hot-swap (rare; only at segment-entry rounds) ------
+            do_swap = const["entry"][r] & const["swap"][sg]
+            state, fin, dop, rem, adv, stall_end, nre, rbytes = lax.cond(
+                do_swap,
+                seam_step,
+                lambda op: (op[0], op[1], op[2], op[3], op[4], op[6], op[7], op[8]),
+                (state, fin, dop, rem, adv, pborn, stall_end, nre, rbytes,
+                 t0, workw, iow, syncw, ckptw, capsg, hopsg, stagedg),
             )
-            rem_stale = jnp.clip(
-                ((fin - t1) + stale_amt) / jnp.maximum(d_cur, 1e-12),
-                0.0, 1.0,
-            )
-            at_risk = run & (cmaxw[None, :] > dop + 0.5) & (
-                t1 + rem_stale * d_cur > tgtb
-            )
-            blocked_p = per_part((blocked, parw_i[None, :]))
-            risk_p = per_part((at_risk, pborn_i))
-            trig_p = (blocked_p | risk_p) & ~stalled_p
-            own_trig_run = own_of(trig_p, pborn_i, False)
-            own_trig_rdy = trig_p[:, jnp.clip(parw_i, 0, P - 1)]
+            d_cur = dur(workw, iow, syncw, dop)
 
-            # Phase B (quota control): triggered partitions re-bid
-            # running + still-ready jobs EDF against the full capacity,
-            # using the same stale-progress projection as the trigger.
-            want_run_q = want_of(rem_stale, tgtb - t1)
-            entryB = (run & own_trig_run) | (still & own_trig_rdy)
-            wantB = jnp.where(run, jnp.maximum(want_run_q, 1.0), want_rdy)
-            grantB = edf_alloc(
-                wantB, entryB, jnp.where(run, pborn, partA), candw, cap_pool
-            )
-
-            # benefit/cost gates: grow only when the saved time beats the
-            # whole-partition stall it causes; shrink only to admit a
-            # blocked job; never preempt a runner to zero.
-            d_new = dur(workw, iow, syncw, grantB)
-            n_run_p = per_part((run, pborn_i), 1.0)
-            own_nrun = own_of(n_run_p, pborn_i, 1.0)
-            own_hops = hopsg[jnp.clip(pborn_i, 0, P - 1)]
-            stall_c = (
-                cfg.fixed_s + cfg.decision_s + own_hops * cfg.per_hop_s
-                + ckptw[None, :] * jnp.abs(grantB - dop) * cfg.inv_bw
-            )
-            benefit = rem_stale * (d_cur - d_new)
-            grow_ok = benefit > stall_c * jnp.maximum(own_nrun, 1.0) * cfg.realloc_gate
-            blocked_own = own_of(blocked_p, pborn_i, False)
-            g = grantB
-            g = jnp.where(g > dop, jnp.where(grow_ok, g, dop), g)
-            g = jnp.where((g < dop) & ~blocked_own, dop, g)
-            g = jnp.where(g < 0.5, dop, g)
-            g = jnp.where(run & own_trig_run, g, dop)
-
-            # Phase B starts: validate against free + net freed tiles,
-            # EDF order, dropping what no longer fits (scalar lines
-            # 209-219).
-            freed_p = per_part((run & own_trig_run, pborn_i),
-                               jnp.maximum(dop - g, 0.0))
-            grown_p = per_part((run & own_trig_run, pborn_i),
-                               jnp.maximum(g - dop, 0.0))
-            availB = free2 + freed_p - grown_p
-            dB = jnp.where(still & own_trig_rdy, grantB, 0.0)
-            dB_s = jnp.take(dB, permr, axis=1)
-            exclB, _, availg = _class_prefix(
-                cfg, jnp.take(partA, permr, axis=1), availB, dB_s.dtype
-            )
-            keep_s = (dB_s > 0) & (exclB(dB_s) + dB_s <= availg + 0.5)
-            started2 = jnp.take(keep_s, ipermr, axis=1)
-            started = started1 | started2
-            grant = jnp.where(
-                run, g, jnp.where(started1, grantA, jnp.where(started2, grantB, 0.0))
-            )
-
-        # ---- apply: starts -------------------------------------------
-        # a job admitted before this round opened was blocked on
-        # capacity; it starts at the in-round release event, not at adm
-        d_start = dur(workw, iow, syncw, grant)
-        start_t = jnp.where(
-            adm >= t0 - 1e-9,
-            adm,
-            jnp.minimum(jnp.maximum(own_freed, t0), t1),
-        )
-        state = jnp.where(started, RUN, state)
-        start = jnp.where(started, start_t, start)
-        fin = jnp.where(started, start_t + rem * d_start, fin)
-        pborn = jnp.where(started, parw[None, :], pborn)
-        subb = jnp.where(started, subw[None, :], subb)
-        tgtb = jnp.where(started, tgtw[None, :], tgtb)
-
-        # ---- apply: resizes / preempts (tp, ads) ---------------------
-        if pol in (_TP, _ADS):
-            resized = run & (jnp.abs(grant - dop) > 0.5)
-            if pol == _TP:
-                preempt = resized & (grant < 0.5)
+            # ---- finishes ------------------------------------------------
+            # drop_mode 1: cyc's unconditional budget enforcement at the
+            # bound sub-deadline; drop_mode 2: hard e2e-deadline dequeue;
+            # drop_mode 0 (the runner's soft default): late jobs finish late
+            run = state == RUN
+            if cfg.drop_mode == 1:
+                lim_run = subb
+            elif cfg.drop_mode == 2:
+                lim_run = jnp.broadcast_to(e2ew[None, :], (R, W))
             else:
-                preempt = jnp.zeros_like(resized)
-            moved_j = jnp.where(
-                resized,
-                ckptw[None, :] * jnp.where(preempt, dop, jnp.abs(grant - dop)),
+                lim_run = jnp.full((R, W), jnp.inf, dtype=jnp.float32)
+            drop_run = run & (lim_run <= t1) & (fin > lim_run + 1e-9)
+            done_now = run & (fin <= t1) & ~drop_run
+            state = jnp.where(done_now, DONE, state)
+
+            # ---- readiness (release passed + all predecessors resolved) --
+            pend = state == PEND
+            pcodes = codes[:, predw.reshape(-1)].reshape(R, W, PM)
+            unresolved = jnp.any(jnp.isinf(pcodes), axis=-1)
+            rtimes = jnp.where(pcodes < 0, -pcodes - 1.0, pcodes)
+            res_t = jnp.maximum(relw[None, :], jnp.max(rtimes, axis=-1))
+            newready = pend & (relw[None, :] <= t1) & ~unresolved
+            state = jnp.where(newready, READY, state)
+            ready_t = jnp.where(newready, res_t, ready_t)
+            deg = jnp.where(newready, jnp.any(pcodes < -0.5, axis=-1), deg)
+
+            # ---- deadline drops (exact drop times, backdated) ------------
+            if cfg.drop_mode == 1:
+                lim_rdy = jnp.broadcast_to(subw[None, :], (R, W))
+            elif cfg.drop_mode == 2:
+                lim_rdy = jnp.broadcast_to(e2ew[None, :], (R, W))
+            else:
+                lim_rdy = jnp.full((R, W), jnp.inf, dtype=jnp.float32)
+            rdy = state == READY
+            drop_rdy = rdy & (lim_rdy <= t1)
+            droptime = jnp.where(
+                drop_run, lim_run, jnp.maximum(lim_rdy, ready_t)
+            )
+            dropping = drop_run | drop_rdy
+            rem_d = jnp.where(
+                drop_run,
+                jnp.clip((fin - droptime) / jnp.maximum(d_cur, 1e-12), 0.0, 1.0),
+                rem,
+            )
+            d_plan = dur(workw, iow, syncw, pdw[None, :])
+            dwork = dwork + jnp.sum(
+                jnp.where(dropping, rem_d * d_plan * pdw[None, :], 0.0), axis=1
+            )
+            state = jnp.where(dropping, DROP, state)
+            fin = jnp.where(dropping, droptime, fin)
+            deg = jnp.where(dropping, 1.0, deg)
+
+            # in-round capacity-release times per partition: a job that sat
+            # queued through earlier rounds can only start at the event that
+            # made room (a completion or drop), never back at its admission
+            # time — the scalar starts it from that event's callback
+            fpart = jnp.where(drop_rdy, parw[None, :], pborn).astype(jnp.int32)
+            freeing = done_now | dropping
+            ar_p = jnp.arange(P, dtype=jnp.int32)
+            freed_t_p = jnp.max(
+                jnp.where(
+                    freeing[..., None] & (fpart[..., None] == ar_p),
+                    fin[..., None], t0,
+                ),
+                axis=1,
+            )
+
+            # ---- finish codes (idempotent re-derivation for the window) --
+            terminal = state >= DONE
+            code_w = jnp.where(
+                terminal, jnp.where(deg > 0.5, -fin - 1.0, fin), jnp.inf
+            )
+            codes = lax.dynamic_update_slice(codes, code_w, (0, lo))
+
+            # ---- accounting: tile presence of the pre-policy state -------
+            run = state == RUN
+            alloc_p = jnp.sum(
+                jnp.where(
+                    run[..., None] & (pborn.astype(jnp.int32)[..., None] == ar_p),
+                    dop[..., None], 0.0,
+                ),
+                axis=1,
+            )
+            presence = jnp.where(
+                state >= RUN,
+                dop * jnp.clip(jnp.minimum(fin, t1) - jnp.maximum(start, t0), 0.0, None),
                 0.0,
-            )
-            ohres = pborn.astype(jnp.int32)[..., None] == ar_p
-            moved_p = jnp.sum(
-                jnp.where(ohres, moved_j[..., None], 0.0), axis=1
-            )
-            changed_p = jnp.any(resized[..., None] & ohres, axis=1)
-            stall_p = jnp.where(
-                changed_p,
-                cfg.fixed_s + cfg.decision_s + hopsg[None, :] * cfg.per_hop_s
-                + moved_p * cfg.inv_bw,
-                0.0,
-            )
-            stall_end = jnp.maximum(stall_end, t1 + stall_p)
-            rem_now = jnp.clip((fin - t1) / jnp.maximum(d_cur, 1e-12), 0.0, 1.0)
-            d_res = dur(workw, iow, syncw, grant)
-            fin = jnp.where(resized & ~preempt, t1 + rem_now * d_res, fin)
-            dop = jnp.where(resized & ~preempt, grant, dop)
-            rem = jnp.where(preempt, rem_now, rem)
-            state = jnp.where(preempt, READY, state)
-            dop = jnp.where(preempt, 0.0, dop)
-            fin = jnp.where(preempt, jnp.inf, fin)
-            # whole-partition freeze: survivors wait out the stall
-            stall_own = jnp.take_along_axis(
-                jnp.concatenate([stall_p, jnp.zeros((R, 1))], axis=1),
-                jnp.clip(pborn.astype(jnp.int32), 0, P), axis=1,
-            )
-            frozen = (state == RUN) & ~started & (stall_own > 0)
-            fin = jnp.where(frozen, fin + stall_own, fin)
-            # the freeze is where the scalar engine syncs progress: the
-            # staleness clock restarts at the stall's end
-            adv = jnp.where(
-                frozen | (resized & ~preempt), t1 + stall_own, adv
-            )
-            nre = nre + jnp.sum(changed_p.astype(jnp.float32), axis=1)
-            rbytes = rbytes + jnp.sum(moved_p, axis=1)
+            ).sum(axis=1)
+            ov_p = jnp.clip(jnp.minimum(stall_end, t1) - t0, 0.0, None)
+            realloc_r = jnp.sum(alloc_p * ov_p, axis=1)
 
-        dop = jnp.where(started, grant, dop)
-        adv = jnp.where(started, start_t, adv)
+        with jax.named_scope("policy"):
+            # ---- policy pass ---------------------------------------------
+            parw_i = parw.astype(jnp.int32)
+            stall_rdy = stall_end[:, jnp.clip(parw_i, 0, P - 1)]
+            adm = jnp.maximum(ready_t, stall_rdy)
+            if pol == _CYC or (pol == _ADS and cfg.admission):
+                adm = jnp.maximum(adm, ertw[None, :])
+            can = (state == READY) & (adm <= t1 + 1e-12)
+            own_freed = freed_t_p[:, jnp.clip(parw_i, 0, P - 1)]
 
-        # ---- accumulate tile-seconds into the segment buckets --------
-        start_corr = jnp.sum(
-            jnp.where(started, grant * jnp.clip(t1 - start_t, 0.0, None), 0.0),
-            axis=1,
-        )
-        busy_r = jnp.clip(presence + start_corr - realloc_r, 0.0, None)
-        onehot = (jnp.arange(S_) == sg).astype(busy.dtype)
-        busy = busy + onehot[None, :] * busy_r[:, None]
-        rel = rel + onehot[None, :] * realloc_r[:, None]
+            free_p = capsg[None, :] - alloc_p
+            stalled_p = stall_end > t1
 
-        # ---- pack the window back ------------------------------------
-        new_w = (state, ready_t, deg, start, fin, dop, pborn, rem, subb,
-                 tgtb, adv)
-        st = tuple(
-            lax.dynamic_update_slice(a, w, (0, lo))
-            for a, w in zip(st, new_w)
-        )
+            d_lad = (
+                workw[..., None] / (jnp.maximum(candw, 1.0)[None, :, :] * tf)
+                + iow[..., None]
+                + syncw[None, :, None] * jnp.maximum(candw - 1.0, 0.0)[None, :, :]
+            )
+
+            def want_of(rem_f, slack):
+                """fit_quota's ladder target with no tile cap (cap folds in
+                at grant time): smallest candidate meeting the deadline,
+                else the largest rung."""
+                if not cfg.quota_control:
+                    return jnp.broadcast_to(candw[None, :, -1], (R, W))
+                meet = rem_f[..., None] * d_lad <= slack[..., None] + 1e-12
+                first = jnp.argmax(meet, axis=-1)
+                anym = jnp.any(meet, axis=-1)
+                cw = jnp.broadcast_to(candw[None, :, :], (R, W, C))
+                picked = jnp.take_along_axis(cw, first[..., None], axis=-1)[..., 0]
+                return jnp.where(anym, picked, candw[None, :, -1])
+
+            def edf_alloc(want_m, entry_m, part_m, cand_rows, pool, bump=False):
+                """EDF-permute, ladder-allocate, inverse-permute."""
+                with jax.named_scope("alloc"):
+                    want_s = jnp.take(want_m, permr, axis=1)
+                    entry_s = jnp.take(entry_m, permr, axis=1)
+                    part_s = jnp.take(part_m, permr, axis=1)
+                    cand_s = (
+                        jnp.take(cand_rows, permr, axis=0)
+                        if cand_rows.ndim == 2
+                        else cand_rows
+                    )
+                    grant_s = _alloc_ladder(cfg, want_s, entry_s, part_s, cand_s, pool)
+                    if bump:
+                        grant_s = _bump_work_conserving(
+                            cfg, grant_s, entry_s, part_s, cand_s, pool
+                        )
+                    return jnp.take(grant_s, ipermr, axis=1)
+
+            def per_part(mask, val=None):
+                """(R, P) per-partition sum (or any) keyed by an id array."""
+                m, ids = mask
+                oh = jnp.broadcast_to(ids, (R, W))[..., None] == ar_p
+                if val is None:
+                    return jnp.any(m[..., None] & oh, axis=1)
+                v = jnp.broadcast_to(val, (R, W))
+                return jnp.sum(
+                    jnp.where(m[..., None] & oh, v[..., None], 0.0), axis=1
+                )
+
+            def own_of(arr_p, idx_i, padval):
+                pad = jnp.full((R, 1), padval, dtype=arr_p.dtype)
+                return jnp.take_along_axis(
+                    jnp.concatenate([arr_p, pad], axis=1),
+                    jnp.clip(idx_i, 0, P), axis=1,
+                )
+
+            cap_pool = jnp.broadcast_to(capsg, (R, P))
+            if pol in (_CYC, _CYC_S):
+                # runners keep their tiles until they finish: ready jobs bid
+                # on *free* capacity only (under overload the planned slots
+                # collide and instances queue exactly like the scalar)
+                want = jnp.where(can, pdw[None, :], 0.0)
+                grant = edf_alloc(
+                    want, can, jnp.broadcast_to(parw[None, :], (R, W)),
+                    pdw[:, None], free_p,
+                )
+                started = can & (grant > 0.5)
+            elif pol == _TP:
+                # tp re-walks ready+running EDF against the *full* capacity
+                # on every queue change; between rounds the fixed point of
+                # quota+bump is stationary, so recomputing it each round
+                # reproduces the event-driven walk as long as the allocator
+                # reaches the same fixed point (alloc_iters / bump_passes)
+                slack_rdy = jnp.broadcast_to(subw[None, :], (R, W)) - jnp.maximum(adm, t0)
+                want_rdy = jnp.where(can, want_of(rem, slack_rdy), 0.0)
+                rem_run = jnp.clip(
+                    (fin - t1) / jnp.maximum(d_cur, 1e-12), 0.0, 1.0
+                )
+                want_run_q = want_of(rem_run, subb - t1)
+                own_stalled = own_of(
+                    stalled_p, pborn.astype(jnp.int32), True
+                )
+                want_run = jnp.where(own_stalled, dop, want_run_q)
+                want = jnp.where(run, want_run, want_rdy)
+                grant = edf_alloc(
+                    want, can | run, jnp.where(run, pborn, parw[None, :]),
+                    candw, cap_pool, bump=True,
+                )
+                started = can & (grant > 0.5)
+            else:
+                with jax.named_scope("ads"):
+                    # ---- ads Algorithm 2, mirrored in two phases --------------
+                    # Phase A (fast path): ready jobs start on *free* tiles at
+                    # their quota while running jobs hold their allocation —
+                    # under pressure this yields the scalar engine's best-effort
+                    # small starts (fit_quota degrades to the largest rung that
+                    # fits free), which is what later makes them at-risk and
+                    # drives the grow cascade.
+                    pborn_i = pborn.astype(jnp.int32)
+                    cmaxw = candw[:, -1]
+                    slack_rdy = jnp.broadcast_to(tgtw[None, :], (R, W)) - jnp.maximum(adm, t0)
+                    want_rdy = jnp.where(can, want_of(rem, slack_rdy), 0.0)
+                    partA = jnp.broadcast_to(parw[None, :], (R, W))
+                    grantA = edf_alloc(want_rdy, can, partA, candw, free_p)
+                    started1 = can & (grantA > 0.5)
+
+                    # ChkTrigger on the post-fast-path state; the running set is
+                    # the pre-start snapshot, as in the scalar policy.
+                    alloc2 = alloc_p + per_part((started1, parw_i[None, :]), grantA)
+                    free2 = cap_pool - alloc2
+                    still = can & ~started1
+                    own_free2 = free2[:, jnp.clip(parw_i, 0, P - 1)]
+                    blocked = still & (want_rdy > own_free2 + 0.5)
+                    # The scalar engine syncs ``job.progress`` only at the job's
+                    # chunk boundaries (n_chunks per duration) and at realloc
+                    # freezes, so its projection ``now + remaining`` runs on
+                    # progress stale by up to one chunk interval — a job started
+                    # with a thin margin drifts into at-risk between chunk
+                    # syncs even though it is on track.  ``adv`` anchors the
+                    # chunk grid (start / freeze end); the staleness at t1 is
+                    # the time since the last chunk boundary before t1.
+                    chunk_iv = jnp.maximum(d_cur, 1e-12) / jnp.float32(cfg.n_chunks)
+                    stale_amt = jnp.where(
+                        run,
+                        jnp.mod(jnp.clip(t1 - adv, 0.0, None), chunk_iv),
+                        0.0,
+                    )
+                    rem_stale = jnp.clip(
+                        ((fin - t1) + stale_amt) / jnp.maximum(d_cur, 1e-12),
+                        0.0, 1.0,
+                    )
+                    at_risk = run & (cmaxw[None, :] > dop + 0.5) & (
+                        t1 + rem_stale * d_cur > tgtb
+                    )
+                    blocked_p = per_part((blocked, parw_i[None, :]))
+                    risk_p = per_part((at_risk, pborn_i))
+                    trig_p = (blocked_p | risk_p) & ~stalled_p
+                    own_trig_run = own_of(trig_p, pborn_i, False)
+                    own_trig_rdy = trig_p[:, jnp.clip(parw_i, 0, P - 1)]
+
+                    # Phase B (quota control): triggered partitions re-bid
+                    # running + still-ready jobs EDF against the full capacity,
+                    # using the same stale-progress projection as the trigger.
+                    want_run_q = want_of(rem_stale, tgtb - t1)
+                    entryB = (run & own_trig_run) | (still & own_trig_rdy)
+                    wantB = jnp.where(run, jnp.maximum(want_run_q, 1.0), want_rdy)
+                    grantB = edf_alloc(
+                        wantB, entryB, jnp.where(run, pborn, partA), candw, cap_pool
+                    )
+
+                    # benefit/cost gates: grow only when the saved time beats the
+                    # whole-partition stall it causes; shrink only to admit a
+                    # blocked job; never preempt a runner to zero.
+                    d_new = dur(workw, iow, syncw, grantB)
+                    n_run_p = per_part((run, pborn_i), 1.0)
+                    own_nrun = own_of(n_run_p, pborn_i, 1.0)
+                    own_hops = hopsg[jnp.clip(pborn_i, 0, P - 1)]
+                    stall_c = (
+                        cfg.fixed_s + cfg.decision_s + own_hops * cfg.per_hop_s
+                        + ckptw[None, :] * jnp.abs(grantB - dop) * cfg.inv_bw
+                    )
+                    benefit = rem_stale * (d_cur - d_new)
+                    grow_ok = benefit > stall_c * jnp.maximum(own_nrun, 1.0) * cfg.realloc_gate
+                    blocked_own = own_of(blocked_p, pborn_i, False)
+                    g = grantB
+                    g = jnp.where(g > dop, jnp.where(grow_ok, g, dop), g)
+                    g = jnp.where((g < dop) & ~blocked_own, dop, g)
+                    g = jnp.where(g < 0.5, dop, g)
+                    g = jnp.where(run & own_trig_run, g, dop)
+
+                    # Phase B starts: validate against free + net freed tiles,
+                    # EDF order, dropping what no longer fits (scalar lines
+                    # 209-219).
+                    freed_p = per_part((run & own_trig_run, pborn_i),
+                                       jnp.maximum(dop - g, 0.0))
+                    grown_p = per_part((run & own_trig_run, pborn_i),
+                                       jnp.maximum(g - dop, 0.0))
+                    availB = free2 + freed_p - grown_p
+                    dB = jnp.where(still & own_trig_rdy, grantB, 0.0)
+                    dB_s = jnp.take(dB, permr, axis=1)
+                    exclB, _, availg = _class_prefix(
+                        cfg, jnp.take(partA, permr, axis=1), availB, dB_s.dtype
+                    )
+                    keep_s = (dB_s > 0) & (exclB(dB_s) + dB_s <= availg + 0.5)
+                    started2 = jnp.take(keep_s, ipermr, axis=1)
+                    started = started1 | started2
+                    grant = jnp.where(
+                    run, g, jnp.where(started1, grantA, jnp.where(started2, grantB, 0.0))
+                )
+
+        with jax.named_scope("apply"):
+            # ---- apply: starts -------------------------------------------
+            # a job admitted before this round opened was blocked on
+            # capacity; it starts at the in-round release event, not at adm
+            d_start = dur(workw, iow, syncw, grant)
+            start_t = jnp.where(
+                adm >= t0 - 1e-9,
+                adm,
+                jnp.minimum(jnp.maximum(own_freed, t0), t1),
+            )
+            state = jnp.where(started, RUN, state)
+            start = jnp.where(started, start_t, start)
+            fin = jnp.where(started, start_t + rem * d_start, fin)
+            pborn = jnp.where(started, parw[None, :], pborn)
+            subb = jnp.where(started, subw[None, :], subb)
+            tgtb = jnp.where(started, tgtw[None, :], tgtb)
+
+            # ---- apply: resizes / preempts (tp, ads) ---------------------
+            if pol in (_TP, _ADS):
+                resized = run & (jnp.abs(grant - dop) > 0.5)
+                if pol == _TP:
+                    preempt = resized & (grant < 0.5)
+                else:
+                    preempt = jnp.zeros_like(resized)
+                moved_j = jnp.where(
+                    resized,
+                    ckptw[None, :] * jnp.where(preempt, dop, jnp.abs(grant - dop)),
+                    0.0,
+                )
+                ohres = pborn.astype(jnp.int32)[..., None] == ar_p
+                moved_p = jnp.sum(
+                    jnp.where(ohres, moved_j[..., None], 0.0), axis=1
+                )
+                changed_p = jnp.any(resized[..., None] & ohres, axis=1)
+                stall_p = jnp.where(
+                    changed_p,
+                    cfg.fixed_s + cfg.decision_s + hopsg[None, :] * cfg.per_hop_s
+                    + moved_p * cfg.inv_bw,
+                    0.0,
+                )
+                stall_end = jnp.maximum(stall_end, t1 + stall_p)
+                rem_now = jnp.clip((fin - t1) / jnp.maximum(d_cur, 1e-12), 0.0, 1.0)
+                d_res = dur(workw, iow, syncw, grant)
+                fin = jnp.where(resized & ~preempt, t1 + rem_now * d_res, fin)
+                dop = jnp.where(resized & ~preempt, grant, dop)
+                rem = jnp.where(preempt, rem_now, rem)
+                state = jnp.where(preempt, READY, state)
+                dop = jnp.where(preempt, 0.0, dop)
+                fin = jnp.where(preempt, jnp.inf, fin)
+                # whole-partition freeze: survivors wait out the stall
+                stall_own = jnp.take_along_axis(
+                    jnp.concatenate([stall_p, jnp.zeros((R, 1))], axis=1),
+                    jnp.clip(pborn.astype(jnp.int32), 0, P), axis=1,
+                )
+                frozen = (state == RUN) & ~started & (stall_own > 0)
+                fin = jnp.where(frozen, fin + stall_own, fin)
+                # the freeze is where the scalar engine syncs progress: the
+                # staleness clock restarts at the stall's end
+                adv = jnp.where(
+                    frozen | (resized & ~preempt), t1 + stall_own, adv
+                )
+                nre = nre + jnp.sum(changed_p.astype(jnp.float32), axis=1)
+                rbytes = rbytes + jnp.sum(moved_p, axis=1)
+
+            dop = jnp.where(started, grant, dop)
+            adv = jnp.where(started, start_t, adv)
+
+            # ---- accumulate tile-seconds into the segment buckets --------
+            start_corr = jnp.sum(
+                jnp.where(started, grant * jnp.clip(t1 - start_t, 0.0, None), 0.0),
+                axis=1,
+            )
+            busy_r = jnp.clip(presence + start_corr - realloc_r, 0.0, None)
+            onehot = (jnp.arange(S_) == sg).astype(busy.dtype)
+            busy = busy + onehot[None, :] * busy_r[:, None]
+            rel = rel + onehot[None, :] * realloc_r[:, None]
+
+        with jax.named_scope("window"):
+            # ---- pack the window back ------------------------------------
+            new_w = (state, ready_t, deg, start, fin, dop, pborn, rem, subb,
+                     tgtb, adv)
+            st = tuple(
+                lax.dynamic_update_slice(a, w, (0, lo))
+                for a, w in zip(st, new_w)
+            )
         return st, codes, stall_end, busy, rel, nre, rbytes, dwork
 
     def loop(st, codes, stall_end, busy, rel, nre, rbytes, dwork):
@@ -915,24 +924,36 @@ def simulate(
     )
     loop = _LOOP_CACHE.get(key)
     if loop is None:
+        metrics.count("soa_loop_builds")
         loop = _LOOP_CACHE[key] = round_loop(cfg, const_np)
+    metrics.count("soa_rounds", int(const_np["t0"].shape[0]))
+    metrics.count("soa_lanes", R)
 
-    st, codes, stall_end, busy, rel, nre, rbytes, dwork = loop(
-        jnp.asarray(lanes_np["work"]),
-        jnp.asarray(lanes_np["io"]),
-        jnp.asarray(lanes_np["codes0"]),
-    )
-    return {
-        "state": np.asarray(st[F_STATE]),
-        "ready_t": np.asarray(st[F_READY]),
-        "deg": np.asarray(st[F_DEG]),
-        "start": np.asarray(st[F_START]),
-        "fin": np.asarray(st[F_FIN]),
-        "dop": np.asarray(st[F_DOP]),
-        "codes": np.asarray(codes),
-        "busy": np.asarray(busy, dtype=np.float64),
-        "realloc": np.asarray(rel, dtype=np.float64),
-        "n_realloc": np.asarray(nre, dtype=np.float64),
-        "realloc_bytes": np.asarray(rbytes, dtype=np.float64),
-        "dropped_work": np.asarray(dwork, dtype=np.float64),
-    }
+    # while the registry is on, each phase waits for its own result (which
+    # the next phase would wait for anyway), so its span is its own cost
+    with metrics.phase("soa_upload"):
+        lanes = tuple(
+            jnp.asarray(lanes_np[k]) for k in ("work", "io", "codes0")
+        )
+        if metrics.enabled():
+            jax.block_until_ready(lanes)
+    with metrics.phase("soa_loop"):
+        out = loop(*lanes)
+        if metrics.enabled():
+            jax.block_until_ready(out)
+    with metrics.phase("soa_fetch"):
+        st, codes, stall_end, busy, rel, nre, rbytes, dwork = out
+        return {
+            "state": np.asarray(st[F_STATE]),
+            "ready_t": np.asarray(st[F_READY]),
+            "deg": np.asarray(st[F_DEG]),
+            "start": np.asarray(st[F_START]),
+            "fin": np.asarray(st[F_FIN]),
+            "dop": np.asarray(st[F_DOP]),
+            "codes": np.asarray(codes),
+            "busy": np.asarray(busy, dtype=np.float64),
+            "realloc": np.asarray(rel, dtype=np.float64),
+            "n_realloc": np.asarray(nre, dtype=np.float64),
+            "realloc_bytes": np.asarray(rbytes, dtype=np.float64),
+            "dropped_work": np.asarray(dwork, dtype=np.float64),
+        }

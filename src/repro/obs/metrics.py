@@ -9,7 +9,10 @@ external profiler.  This module is a process-global registry of
   (``count("skeleton_cache_hit")``), and
 * **phase timers** — wall-clock accumulators around named phases
   (``with phase("engine_run"): ...``), recording call count and total
-  seconds.
+  seconds.  While enabled, each phase is also a
+  ``jax.profiler.TraceAnnotation`` named ``repro.<phase>``, so a
+  profiler trace shows it on the host thread, nested in the phases that
+  enclose it and on the same clock as the device's operations.
 
 Everything is **disabled by default**: instrumented call sites pay one
 module-level boolean check and nothing else, so the hot paths the
@@ -28,7 +31,7 @@ from __future__ import annotations
 
 import time
 from contextlib import contextmanager
-from typing import Dict, Iterator, List
+from typing import Dict, Iterator, List, Optional
 
 __all__ = [
     "count",
@@ -43,6 +46,11 @@ _enabled: bool = False
 _counters: Dict[str, float] = {}
 #: name -> [n_calls, total_seconds]
 _phases: Dict[str, List[float]] = {}
+#: prefix of the profiler annotation of every phase
+ANNOTATION_PREFIX = "repro."
+#: ``jax.profiler.TraceAnnotation``, imported on the first enabled phase
+#: so that importing the registry imports nothing of jax
+_annotation = None
 
 
 def enable(on: bool = True) -> None:
@@ -69,17 +77,26 @@ def count(name: str, value: float = 1) -> None:
 
 
 @contextmanager
-def phase(name: str) -> Iterator[None]:
+def phase(name: str, **args) -> Iterator[Optional[object]]:
     """Time a named phase (no-op while disabled).
+
+    While enabled the phase is also a profiler annotation
+    ``repro.<name>`` carrying ``args``; the context yields it, so that
+    a phase can add args it only learns inside
+    (``span.set_metadata(W=...)``).  Disabled, it yields None.
 
     Re-entrant in the trivial sense: nested/repeated phases of the same
     name accumulate into one bucket."""
     if not _enabled:
-        yield
+        yield None
         return
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation as _annotation
     t0 = time.perf_counter()
     try:
-        yield
+        with _annotation(ANNOTATION_PREFIX + name, **args) as span:
+            yield span
     finally:
         dt = time.perf_counter() - t0
         slot = _phases.get(name)

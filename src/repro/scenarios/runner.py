@@ -38,7 +38,7 @@ from ..core.runtime import (
 from ..core.sim import SimConfig, Simulator, SimReport
 from ..core.sim.batch import LaneSimulator, run_batch, sample_trace_batch
 from ..core.sim.trace import Trace, build_skeleton, sample_trace
-from ..obs import TraceRecorder, attribution_report
+from ..obs import TraceRecorder, attribution_report, metrics
 from ..sweeps.executor import ItemFailure, LocalPoolExecutor
 from ..sweeps.reduce import SweepReducer
 from ..sweeps.rows import SweepRow
@@ -300,6 +300,7 @@ def build_trace(spec: ScenarioSpec) -> Trace:
     return sample_trace(skel, model, scen, spec.seed)
 
 
+@metrics.phase("stack_prepare")
 def _prepare_run(spec: ScenarioSpec):
     """The per-run setup shared by every backend: mode registration,
     workload stack, and the offline schedule portfolio — so a batched
@@ -474,6 +475,7 @@ def _run_lockstep_group(
 _SOA_LIFE_PAD_HINT: Dict[tuple, float] = {}
 
 
+@metrics.phase("soa_run")
 def _run_soa(
     spec: ScenarioSpec,
     seeds: Sequence[int],
@@ -520,35 +522,43 @@ def _run_soa(
     )
     opt = opt0
     for attempt in range(3):
-        problem = soa.build_problem(
-            wf, model, sched, portfolio,
-            _make_run_policy(spec, portfolio), scen, duration,
-            replan=spec.replan, n_lanes=len(seeds),
-            drop_policy=spec.drop_policy, options=opt,
-        )
-        try:
-            reports = soa.run_problem(problem, btrace, seeds)
-        except soa.SoaWindowOverflow:
-            if problem.life >= duration or attempt == 2:
-                raise
-            warnings.warn(
-                f"SoA job window ({problem.life:.3f}s) overflowed under "
-                "overload; retrying with a "
-                + ("doubled" if attempt == 0 else "full-horizon")
-                + " window (recompiles the round loop)",
-                RuntimeWarning,
-                stacklevel=2,
-            )
-            pad = problem.life if attempt == 0 else duration
-            opt = dataclasses.replace(
-                opt0, life_pad_s=opt0.life_pad_s + pad
-            )
-        else:
-            if options is None and opt.life_pad_s > _SOA_LIFE_PAD_HINT.get(
-                hint_key, 0.0
-            ):
-                _SOA_LIFE_PAD_HINT[hint_key] = opt.life_pad_s
-            return reports
+        metrics.count("soa_attempts")
+        with metrics.phase("soa_attempt") as span:
+            with metrics.phase("soa_build"):
+                problem = soa.build_problem(
+                    wf, model, sched, portfolio,
+                    _make_run_policy(spec, portfolio), scen, duration,
+                    replan=spec.replan, n_lanes=len(seeds),
+                    drop_policy=spec.drop_policy, options=opt,
+                )
+            if span is not None:
+                span.set_metadata(
+                    W=problem.cfg.W, rounds=len(problem.const["t0"]),
+                    full_horizon=problem.life >= duration,
+                )
+            try:
+                reports = soa.run_problem(problem, btrace, seeds)
+            except soa.SoaWindowOverflow:
+                if problem.life >= duration or attempt == 2:
+                    raise
+                warnings.warn(
+                    f"SoA job window ({problem.life:.3f}s) overflowed under "
+                    "overload; retrying with a "
+                    + ("doubled" if attempt == 0 else "full-horizon")
+                    + " window (recompiles the round loop)",
+                    RuntimeWarning,
+                    stacklevel=3,
+                )
+                pad = problem.life if attempt == 0 else duration
+                opt = dataclasses.replace(
+                    opt0, life_pad_s=opt0.life_pad_s + pad
+                )
+                continue
+        if options is None and opt.life_pad_s > _SOA_LIFE_PAD_HINT.get(
+            hint_key, 0.0
+        ):
+            _SOA_LIFE_PAD_HINT[hint_key] = opt.life_pad_s
+        return reports
 
 
 # ---------------------------------------------------------------------------

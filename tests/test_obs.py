@@ -314,3 +314,103 @@ def test_metrics_counts_and_phases():
     assert work["total_s"] >= 0
     assert work["mean_s"] == pytest.approx(work["total_s"] / 2)
     assert metrics.snapshot() == {"counters": {}, "phases": {}}
+
+
+# ---------------------------------------------------------------------------
+# the SoA path's phases and counters
+# ---------------------------------------------------------------------------
+#: the registry's phases on every SoA call, and inside each round-loop attempt
+SOA_CALL_PHASES = ("soa_run", "stack_prepare", "trace_sample",
+                   "trace_sample_draws", "trace_sample_fetch")
+SOA_ATTEMPT_PHASES = ("soa_attempt", "soa_build", "soa_lanes", "soa_upload",
+                      "soa_loop", "soa_fetch", "soa_assemble",
+                      "soa_assemble_arrays", "soa_assemble_lanes")
+SOA_LANES = 4
+
+
+@pytest.fixture(scope="module")
+def soa_call(tmp_path_factory):
+    """One small SoA call with the registry on, under the profiler and
+    with no round loop compiled yet, and the same call with it off."""
+    import jax
+
+    from repro.core.sim import soa
+    from repro.core.sim import soa_kernels as K
+
+    spec = _spec("rate_churn", "cyc")
+    seeds = list(range(SOA_LANES))
+    log_dir = str(tmp_path_factory.mktemp("soa_profile"))
+    built = []
+    real_build = soa.build_problem
+
+    def recording(*args, **kwargs):
+        built.append(real_build(*args, **kwargs))
+        return built[-1]
+
+    K.clear_kernel_cache()
+    soa.build_problem = recording
+    metrics.reset()
+    metrics.enable()
+    try:
+        jax.profiler.start_trace(log_dir)
+        try:
+            on = run(spec, seeds=seeds, backend="soa", fallback=False)
+        finally:
+            jax.profiler.stop_trace()
+        snap = metrics.snapshot(reset_after=True)
+    finally:
+        metrics.enable(False)
+        soa.build_problem = real_build
+    off = run(spec, seeds=seeds, backend="soa", fallback=False)
+    return {"on": on, "off": off, "snap": snap, "built": built, "log_dir": log_dir}
+
+
+def test_soa_reports_do_not_depend_on_the_registry(soa_call):
+    from repro.core.sim.batch import reports_identical
+
+    assert len(soa_call["on"]) == len(soa_call["off"]) == SOA_LANES
+    for a, b in zip(soa_call["on"], soa_call["off"]):
+        assert reports_identical(a, b)
+
+
+def test_soa_call_records_every_phase_and_counter(soa_call):
+    snap, [problem] = soa_call["snap"], soa_call["built"]
+    phases = snap["phases"]
+    for name in SOA_CALL_PHASES + SOA_ATTEMPT_PHASES:
+        assert phases[name]["n"] == 1, name
+    assert snap["counters"]["soa_attempts"] == 1
+    assert snap["counters"]["soa_lanes"] == SOA_LANES
+    assert snap["counters"]["soa_rounds"] == len(problem.const["t0"])
+    assert snap["counters"]["soa_loop_builds"] == 1
+    assert "soa_window_overflows" not in snap["counters"]
+    # the parts of a call lie inside it
+    assert phases["soa_loop"]["total_s"] <= phases["soa_attempt"]["total_s"]
+    assert phases["soa_attempt"]["total_s"] <= phases["soa_run"]["total_s"]
+    lanes = phases["soa_assemble_lanes"]["total_s"]
+    assert lanes <= phases["soa_assemble"]["total_s"]
+
+
+def test_soa_phases_are_profiler_spans(soa_call):
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    [path] = glob.glob(os.path.join(soa_call["log_dir"], "**", "*.xplane.pb"),
+                       recursive=True)
+    spans = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("repro."):
+                    spans.setdefault(ev.name, []).append(
+                        (plane.name, line.name, ev.start_ns, ev.end_ns,
+                         dict(ev.stats)))
+    [(plane, line, a, b, args)] = spans["repro.soa_attempt"]
+    [problem] = soa_call["built"]
+    assert args["W"] == problem.cfg.W
+    assert args["rounds"] == len(problem.const["t0"])
+    for name in ("repro.soa_loop", "repro.soa_assemble_lanes"):
+        [(plane2, line2, a2, b2, _args)] = spans[name]
+        assert (plane2, line2) == (plane, line)  # the same host thread
+        assert a <= a2 <= b2 <= b
