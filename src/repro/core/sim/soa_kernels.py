@@ -206,6 +206,25 @@ def ladder_grant_reference(limit: np.ndarray, cand: np.ndarray) -> np.ndarray:
     return np.max(np.where(ok, cand, 0.0), axis=-1)
 
 
+def _pick(table, idx):
+    """``table[..., idx]`` per element: ``table`` is ``(..., K)`` and
+    broadcasts against ``idx[..., None]``; ``idx`` is an int array in
+    ``[0, K)``, clipped by the caller.
+
+    A static chain of K selects over the small minor axis (partitions,
+    bins, ladder rungs).  A ``take_along_axis`` with an index that
+    varies per lane and per job lowers on a TPU to an element-by-element
+    gather over the whole ``(R, W)`` window; the selects stay on the
+    vector unit, and copy the same value bit for bit (``inf`` and
+    booleans included, which a one-hot product would not)."""
+    out = jnp.broadcast_to(
+        table[..., 0], jnp.broadcast_shapes(table.shape[:-1], idx.shape)
+    )
+    for k in range(1, table.shape[-1]):
+        out = jnp.where(idx == k, table[..., k], out)
+    return out
+
+
 def _class_prefix(cfg, part_s, cap_p, dtype):
     """Per-partition queue-prefix operators for one sorted queue.
 
@@ -224,7 +243,7 @@ def _class_prefix(cfg, part_s, cap_p, dtype):
     part_i = jnp.clip(part_s.astype(jnp.int32), 0, cfg.P - 1)
     ar_p = jnp.arange(cfg.P, dtype=jnp.int32)[None, :, None]
     onehot = (part_i[:, None, :] == ar_p).astype(dtype)
-    capg = jnp.take_along_axis(cap_p, part_i, axis=1)
+    capg = _pick(cap_p[:, None, :], part_i)
 
     def excl(d):
         x = onehot * d[:, None, :]
@@ -232,7 +251,7 @@ def _class_prefix(cfg, part_s, cap_p, dtype):
 
     def total(d):
         tot = jnp.sum(onehot * d[:, None, :], axis=2)
-        return jnp.take_along_axis(tot, part_i, axis=1)
+        return _pick(tot[:, None, :], part_i)
 
     return excl, total, capg
 
@@ -561,8 +580,7 @@ def _build_loop(cfg: KernelConfig, const: Dict[str, "jnp.ndarray"]):
                 meet = rem_f[..., None] * d_lad <= slack[..., None] + 1e-12
                 first = jnp.argmax(meet, axis=-1)
                 anym = jnp.any(meet, axis=-1)
-                cw = jnp.broadcast_to(candw[None, :, :], (R, W, C))
-                picked = jnp.take_along_axis(cw, first[..., None], axis=-1)[..., 0]
+                picked = _pick(candw[None], first)
                 return jnp.where(anym, picked, candw[None, :, -1])
 
             def edf_alloc(want_m, entry_m, part_m, cand_rows, pool, bump=False):
@@ -596,9 +614,9 @@ def _build_loop(cfg: KernelConfig, const: Dict[str, "jnp.ndarray"]):
 
             def own_of(arr_p, idx_i, padval):
                 pad = jnp.full((R, 1), padval, dtype=arr_p.dtype)
-                return jnp.take_along_axis(
-                    jnp.concatenate([arr_p, pad], axis=1),
-                    jnp.clip(idx_i, 0, P), axis=1,
+                return _pick(
+                    jnp.concatenate([arr_p, pad], axis=1)[:, None, :],
+                    jnp.clip(idx_i, 0, P),
                 )
 
             cap_pool = jnp.broadcast_to(capsg, (R, P))
@@ -785,10 +803,7 @@ def _build_loop(cfg: KernelConfig, const: Dict[str, "jnp.ndarray"]):
                 dop = jnp.where(preempt, 0.0, dop)
                 fin = jnp.where(preempt, jnp.inf, fin)
                 # whole-partition freeze: survivors wait out the stall
-                stall_own = jnp.take_along_axis(
-                    jnp.concatenate([stall_p, jnp.zeros((R, 1))], axis=1),
-                    jnp.clip(pborn.astype(jnp.int32), 0, P), axis=1,
-                )
+                stall_own = own_of(stall_p, pborn.astype(jnp.int32), 0.0)
                 frozen = (state == RUN) & ~started & (stall_own > 0)
                 fin = jnp.where(frozen, fin + stall_own, fin)
                 # the freeze is where the scalar engine syncs progress: the

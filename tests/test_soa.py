@@ -261,6 +261,81 @@ def test_pallas_ladder_grant_blocks_match_jnp(per_lane):
 
 
 # ---------------------------------------------------------------------------
+# per-element picks from small per-partition / per-rung tables
+# ---------------------------------------------------------------------------
+def _take_pick(table, idx):
+    """The ``take_along_axis`` form that :func:`K._pick` replaces."""
+    import jax.numpy as jnp
+
+    full = jnp.broadcast_to(table, idx.shape + table.shape[-1:])
+    return jnp.take_along_axis(full, idx[..., None], axis=-1)[..., 0]
+
+
+@pytest.mark.parametrize("dtype", ["f32", "bool"])
+@pytest.mark.parametrize("K_", [1, 4, 5, 6, 9, 29])
+def test_pick_matches_take_along_axis(K_, dtype):
+    """Lane-varying picks from a (R, 1, K) per-lane table (partitions,
+    with ``own_of``'s pad column as the last one) and from a (1, W, K)
+    per-job table (ladder rungs) equal the gather exactly, ``inf``
+    included."""
+    import jax.numpy as jnp
+
+    R, W = 7, 33
+    rng = np.random.default_rng(K_)
+    idx = rng.integers(0, K_, size=(R, W)).astype(np.int32)
+    idx[0, 0], idx[0, 1], idx[-1, -1] = 0, K_ - 1, K_ - 1  # K - 1: the pad
+    for shape in ((R, 1, K_), (1, W, K_)):
+        if dtype == "bool":
+            table = rng.random(shape) < 0.5
+        else:
+            table = rng.normal(size=shape).astype(np.float32)
+            table[rng.random(shape) < 0.3] = np.inf
+            table[..., -1] = -0.0
+        t, i = jnp.asarray(table), jnp.asarray(idx)
+        got, want = np.asarray(K._pick(t, i)), np.asarray(_take_pick(t, i))
+        assert got.dtype == want.dtype and got.shape == (R, W)
+        np.testing.assert_array_equal(got, want)
+        assert np.array_equal(np.signbit(got), np.signbit(want))
+
+
+@pytest.mark.parametrize("policy", ["cyc", "tp_driven", "ads_tile"])
+def test_round_loop_pick_is_bit_identical(policy, monkeypatch):
+    """The round loop with the select-chain picks returns the same
+    arrays, bit for bit, as with ``take_along_axis`` gathers, in every
+    loop call of a run (overflow retries included)."""
+    spec = ScenarioSpec(scenario=get_scenario("rate_churn"), policy=policy)
+    seeds = list(range(8))
+    real = K.simulate
+
+    def outputs():
+        calls = []
+
+        def recording(cfg, const_np, lanes_np):
+            out = real(cfg, const_np, lanes_np)
+            calls.append(out)
+            return out
+
+        monkeypatch.setattr(K, "simulate", recording)
+        K.clear_kernel_cache()
+        # explicit options: both runs start from the default window,
+        # not from a pad the first run's overflow retry remembered
+        run(spec, seeds=seeds, backend="soa", fallback=False,
+            options=soa.SoaOptions())
+        monkeypatch.setattr(K, "simulate", real)
+        return calls
+
+    got = outputs()
+    monkeypatch.setattr(K, "_pick", _take_pick)
+    want = outputs()
+    K.clear_kernel_cache()
+    assert got and len(got) == len(want)
+    for g, w in zip(got, want):
+        assert g.keys() == w.keys()
+        for k in g:
+            assert np.array_equal(g[k], w[k], equal_nan=True), k
+
+
+# ---------------------------------------------------------------------------
 # property test over random Markov scenarios (mirrors test_batch.py)
 # ---------------------------------------------------------------------------
 try:

@@ -12,6 +12,7 @@ module is imported: only one process at a time may load the TPU
 library, and every test worker imports every test file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -120,6 +121,18 @@ def test_round_loop_names_its_phases_for_v5e(policy, one_chip):
     assert text.startswith("HloModule jit_run,")
     for scope in ("window", "step", "policy", "apply"):
         assert f"/while/body/closed_call/{scope}/" in text, scope
+
+
+@pytest.mark.parametrize("policy", ["cyc", "tp_driven", "ads_tile"])
+def test_round_loop_has_no_element_gathers_for_v5e(policy, one_chip):
+    """A ``take_along_axis`` whose index varies per lane and per job
+    becomes an element-by-element gather over the whole (R, W) window on
+    a TPU.  The round loop picks per-partition and per-rung values with
+    selects instead; only the seam hot-swap's argsort permutations,
+    which run in segment-entry rounds alone, keep the gather."""
+    text = _compiled_loop(policy, "default", one_chip).as_text()
+    names = set(re.findall(r'op_name="([^"]*take_along_axis[^"]*)"', text))
+    assert [n for n in names if "/step/cond/" not in n] == []
 
 
 @pytest.mark.parametrize("per_lane", [False, True])
