@@ -41,9 +41,11 @@ asserts:
 * **structural invariants** (job universe, seam spans, chain universe,
   reservation footprint) match exactly, per seed;
 * the pooled chain-latency **KS statistic** stays under ``--ks-tol``
-  (default 0.08 — the measured dt=1e-3 approximation envelope is
-  0.01-0.06 with the tp_driven quota walk the worst cell, so the gate
-  trips on regression, not on the known round-coalescing bias);
+  (default 0.08 — cyc and ads_tile decide at each round's end, and
+  their measured dt=1e-3 approximation envelope is 0.01-0.06;
+  tp_driven decides at its queue-change instants and reads under 0.01;
+  so the gate trips on regression, not on the known round-coalescing
+  bias);
 * per-cell **CI overlap** on violation rate, realloc waste and mean
   reserved tiles (normal-approximation intervals across seeds).
 

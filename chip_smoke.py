@@ -24,9 +24,7 @@ Phases, in order; the first that fails ends the run:
    scalar oracle (the lockstep engine) and against the same SoA program
    run on this host's CPU backend, at the same seeds: structural
    invariants equal per seed, pooled chain-latency KS <= 0.08, and CI
-   overlap on violation rate, realloc waste and reserved tiles (the
-   oracle's CI excepted where ``KNOWN_ORACLE_GAPS`` says the SoA model
-   misses it on every backend);
+   overlap on violation rate, realloc waste and reserved tiles;
 4. Pallas -- the compiled ladder-grant kernel at the ads_tile shape
    (4096, 80, 6) against the jnp select and the NumPy reference, bit
    for bit.
@@ -62,11 +60,6 @@ KS_TOL = 0.08
 SAMPLE_RTOL = 1e-9
 SAMPLE_ATOL = 1e-15
 GRANT_SHAPE = (4096, 80, 6)
-#: (policy, metric) CI overlaps the SoA kernels miss against the scalar
-#: oracle at 64 seeds on every backend, the CPU included: tp_driven's
-#: per-round quota re-walk under-reports violations on rate_churn.  The
-#: chip is held to the CPU's SoA result on them instead.
-KNOWN_ORACLE_GAPS = {("tp_driven", "violation_rate")}
 
 
 def log(msg: str) -> None:
@@ -186,20 +179,19 @@ def phase_main(policy: str, seeds, device, counter):
     return reports
 
 
-def _gate(tag: str, policy: str, ref, got, known=frozenset()) -> bool:
+def _gate(tag: str, policy: str, ref, got) -> bool:
     """Print the distributional verdicts of ``got`` against ``ref``;
-    True when they hold, CI overlaps of ``known`` metrics excepted."""
+    True when they hold."""
     from benchmarks.check_equivalence import compare_distributional
 
     v = compare_distributional(ref, got, KS_TOL)
     missed = [m for m, (_r, _g, ok) in v["ci"].items() if not ok]
-    ok = v["struct_ok"] and v["ks_ok"] and not set(missed) - known
+    ok = v["struct_ok"] and v["ks_ok"] and not missed
     log(f"[correctness] {policy} vs {tag}: struct {v['struct_ok']} "
         f"KS {v['ks']!r} (tol {KS_TOL}) latencies {v['n']} "
         f"{'ok' if ok else 'FAIL'}")
     for m, (ci_ref, ci_got, overlap) in v["ci"].items():
-        note = "" if overlap else (
-            " (known gap)" if m in known else " FAIL")
+        note = "" if overlap else " FAIL"
         log(f"[correctness]   {m}: {tag} CI {ci_ref} chip CI {ci_got}{note}")
     return ok
 
@@ -217,8 +209,7 @@ def phase_correctness(policy: str, soa_reports, oracle_seeds) -> None:
     ref = run(spec, seeds=oracle_seeds, backend="lockstep")
     with jax.default_device(jax.devices("cpu")[0]):
         host = run(spec, seeds=oracle_seeds, backend="soa", fallback=False)
-    known = {m for p, m in KNOWN_ORACLE_GAPS if p == policy}
-    ok_ref = _gate("oracle", policy, ref, chip, known)
+    ok_ref = _gate("oracle", policy, ref, chip)
     ok_host = _gate("cpu-soa", policy, host, chip)
     if not (ok_ref and ok_host):
         raise SystemExit(f"chip_smoke: {policy} outside the distributional gate")

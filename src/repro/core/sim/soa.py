@@ -22,10 +22,12 @@ the semantics oracle; this backend reproduces it **distributionally**
 (KS on chain-latency distributions, CI agreement on violation rate /
 realloc waste / tiles reserved) and **exactly** on structural
 invariants (job counts, seam times/spans, chain universe).  The known
-approximations — discrete scheduling rounds instead of an event heap,
-bounded fixed-point allocation passes instead of the exact sequential
-queue walk, current-segment deadline bindings for not-yet-started
-straddlers — are documented in ``docs/performance.md#soa-backend``.
+approximations — discrete scheduling rounds instead of an event heap
+(cyc and ads_tile decide at a round's end; tp_driven at its own
+queue-change instants inside the round), bounded fixed-point
+allocation passes instead of the exact sequential queue walk,
+current-segment deadline bindings for not-yet-started straddlers —
+are documented in ``docs/performance.md#soa-backend``.
 """
 from __future__ import annotations
 
@@ -112,11 +114,13 @@ def _drop_mode(policy_name: str, drop_policy: str) -> int:
 class SoaOptions:
     """Tuning knobs of the discrete-round approximation.
 
-    ``dt_s`` is the scheduling-round cadence: smaller tracks the scalar
-    engine's event cadence more closely (the bundled workloads see
-    ~one scheduling event per partition per 2-4 ms), larger is faster.
-    Event *times* are exact regardless (backdated); dt only quantizes
-    when decisions are taken.
+    ``dt_s`` is the scheduling-round cadence.  Event *times* are exact
+    regardless (backdated); for cyc and ads_tile dt quantizes when
+    decisions are taken (at each round's end): smaller tracks the
+    scalar engine's event cadence more closely (the bundled workloads
+    see ~one scheduling event per partition per 2-4 ms), larger is
+    faster.  tp_driven decides at its queue-change instants inside the
+    round, so for it dt only sets how many instants a round batches.
     """
 
     dt_s: float = 1e-3
@@ -124,10 +128,11 @@ class SoaOptions:
     #: extra seconds added to the job-window lifetime bound (how long a
     #: job may stay unresolved past its release before it slides out of
     #: the window).  The default bound assumes jobs resolve by their
-    #: E2E deadline; under ``drop_policy="soft"`` overload queues jobs
-    #: past it — :class:`SoaWindowOverflow` reports when the bound was
-    #: too tight and the runner retries with a doubled window.  The
-    #: effective lifetime is capped at the horizon (full coverage).
+    #: E2E deadline (four of them for tp_driven under soft drops);
+    #: under ``drop_policy="soft"`` overload queues jobs past it —
+    #: :class:`SoaWindowOverflow` reports when the bound was too tight
+    #: and the runner retries with a doubled window.  The effective
+    #: lifetime is capped at the horizon (full coverage).
     life_pad_s: float = 0.0
     #: EDF fixed-point refinement steps; None resolves per policy —
     #: tp_driven's event walk needs the exact sequential fixed point
@@ -355,12 +360,15 @@ def build_problem(
     # (never silently truncates).
     max_hops = max((len(c.nodes) for c in wf.chains), default=4)
     cascade = (max_hops + 4) * dt
-    life = (
-        float(np.max(ddl_off[np.isfinite(ddl_off)]))
-        + cascade
-        + float(opt.life_pad_s)
-    )
-    life = min(max(life, 2 * dt), duration + cascade)
+    life = float(np.max(ddl_off[np.isfinite(ddl_off)])) + cascade
+    if policy_name == "tp_driven" and _drop_mode(policy_name, drop_policy) == 0:
+        # tp_driven's work-conserving walks keep a job queued for several
+        # deadlines: on x1 rate_churn 4 drives in 8,064 outlived twice the
+        # bound, none three times, 1 in about 18,000 0.41 s.  Each overflow
+        # costs a retry and a compile, while four bounds (W 144 -> 256 at
+        # R=64) cost a v5e call 3% more than two
+        life *= 4
+    life = min(max(life + float(opt.life_pad_s), 2 * dt), duration + cascade)
     lo = np.searchsorted(rel, t1s - life, side="left")
     hi = np.searchsorted(rel, t1s, side="right")
     wr = int(opt.window_round)
@@ -612,6 +620,16 @@ def _lanes(problem: SoaProblem, btrace) -> Dict[str, np.ndarray]:
         -problem.sen_release[None, :] - 1.0,
         fin,
     )
+    if problem.cfg.policy == K.POLICY_IDS["tp_driven"]:
+        # tp_driven's walks read their instants to better than float32:
+        # after the codes come what float32 rounded away from each
+        # sensor's time, as the kernel decodes it
+        codes0_lo = np.zeros((R, A1), dtype=f4)
+        sen = codes0[:, problem.n_pad: A1 - 1]
+        decoded = np.where(sen < 0, -sen - f4(1.0), sen)
+        t = np.where(problem.sen_drop[None, :], problem.sen_release[None, :], fin)
+        codes0_lo[:, problem.n_pad: A1 - 1] = t - decoded.astype(np.float64)
+        codes0 = np.concatenate([codes0, codes0_lo], axis=1)
     return {"work": work, "io": io, "codes0": codes0}
 
 

@@ -390,6 +390,43 @@ def test_soa_call_records_every_phase_and_counter(soa_call):
     assert lanes <= phases["soa_assemble"]["total_s"]
 
 
+@pytest.mark.parametrize("policy", ["cyc", "tp_driven"])
+def test_soa_counts_lane_rounds_resizes_and_walks(policy, monkeypatch):
+    """Per round-loop call: its rounds times its lanes, its stalls
+    (``n_realloc``) summed over lanes and, for tp_driven alone, the
+    queue walks its lanes took."""
+    from repro.core.sim import soa
+    from repro.core.sim import soa_kernels as K
+
+    calls = []
+    real = K.simulate
+
+    def recording(cfg, const_np, lanes_np):
+        out = real(cfg, const_np, lanes_np)
+        calls.append((len(const_np["t0"]) * lanes_np["work"].shape[0], out))
+        return out
+
+    monkeypatch.setattr(K, "simulate", recording)
+    metrics.reset()
+    metrics.enable()
+    try:
+        run(_spec("rate_churn", policy), seeds=list(range(SOA_LANES)),
+            backend="soa", fallback=False, options=soa.SoaOptions())
+        counters = metrics.snapshot(reset_after=True)["counters"]
+    finally:
+        metrics.enable(False)
+    assert calls
+    assert counters["soa_lane_rounds"] == sum(n for n, _o in calls)
+    assert counters["soa_resizes"] == sum(int(o["n_realloc"].sum()) for _n, o in calls)
+    if policy == "tp_driven":
+        walks = counters["soa_tp_walks"]
+        assert walks == sum(int(o["tp_walks"].sum()) for _n, o in calls)
+        # the scalar engine walks in about 0.62 of a lane's rounds here
+        assert 0.45 < walks / counters["soa_lane_rounds"] < 0.8
+    else:
+        assert "soa_tp_walks" not in counters
+
+
 def test_soa_phases_are_profiler_spans(soa_call):
     import glob
     import os

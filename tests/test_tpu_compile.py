@@ -86,6 +86,8 @@ def _compile_loop(policy, window, one_chip):
         assert problem.cfg.W >= problem.n_real
     N = problem.n_pad
     A1 = N + len(problem.sen_jids) + 1
+    if policy == "tp_driven":
+        A1 *= 2  # its codes0 carries the codes' float32 remainders after them
     lanes = (
         _sds((R, N), jnp.float32, one_chip),
         _sds((R, N), jnp.float32, one_chip),
