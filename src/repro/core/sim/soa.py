@@ -161,6 +161,7 @@ class SoaProblem:
     considered: np.ndarray      # (n_pad,) bool
     e2e_host: np.ndarray        # (n_pad,) float64 exact
     sinks: List[Tuple[str, int, float, float, str]]  # (chain, pos, t0, ddl, mode)
+    sink_groups: "SinkGroups"
     chain_names: List[str]
     expected: Dict[str, int]
     expected_mode: Dict[str, Dict[str, int]]
@@ -175,6 +176,80 @@ class SoaProblem:
     skeleton_key: tuple
     life: float                 # job-window lifetime bound (seconds)
     win_lo_final: int           # highest window lower bound over rounds
+
+
+@dataclasses.dataclass(frozen=True)
+class SinkGroups:
+    """The chain sinks of a problem as index arrays, for assembling
+    every lane's report from reductions over ``(R, sinks)``.
+
+    ``pos``, ``t0`` and ``ddl`` are each sink's job position, release of
+    its chain's source and chain deadline.  The mode axis ``modes`` is
+    the problem's ``spans`` in order, then any other mode a sink falls
+    in.  ``chain_mode`` is the ``(sinks, chains * modes)`` one-hot of
+    each sink's (chain, mode).  ``chain_ix`` holds each chain's sinks in
+    sink order, ``mode_ix`` each mode of ``spans``'s, both padded to the
+    longest group where ``chain_ok`` / ``mode_ok`` are False.  ``fill``
+    is, per chain, the (mode index, expected sinks) a starvation deficit
+    fills in ``mode_order``.
+    """
+
+    pos: np.ndarray
+    t0: np.ndarray
+    ddl: np.ndarray
+    modes: Tuple[str, ...]
+    chain_mode: np.ndarray
+    chain_ix: np.ndarray
+    chain_ok: np.ndarray
+    mode_ix: np.ndarray
+    mode_ok: np.ndarray
+    expected: np.ndarray
+    fill: Tuple[Tuple[Tuple[int, int], ...], ...]
+
+
+def _padded_groups(key: np.ndarray, n: int) -> Tuple[np.ndarray, np.ndarray]:
+    """The indices where ``key == g`` for each ``g < n``, in order, as
+    rows padded to the longest, and the mask of the real entries."""
+    rows = [np.flatnonzero(key == g) for g in range(n)]
+    L = max((len(r) for r in rows), default=0)
+    ix = np.zeros((n, L), dtype=np.int64)
+    ok = np.zeros((n, L), dtype=bool)
+    for g, r in enumerate(rows):
+        ix[g, : len(r)] = r
+        ok[g, : len(r)] = True
+    return ix, ok
+
+
+def _sink_groups(sinks, chain_names, spans, expected, expected_mode,
+                 mode_order) -> SinkGroups:
+    cname, pos, t0, ddl, smode = zip(*sinks) if sinks else ((),) * 5
+    modes = list(spans)
+    modes += sorted(set(smode) - set(modes))
+    c_of = {c: i for i, c in enumerate(chain_names)}
+    m_of = {m: j for j, m in enumerate(modes)}
+    chain = np.array(list(map(c_of.__getitem__, cname)), dtype=np.int64)
+    mode = np.array(list(map(m_of.__getitem__, smode)), dtype=np.int64)
+    C, M = len(chain_names), len(modes)
+    chain_mode = np.zeros((len(sinks), C * M))
+    chain_mode[np.arange(len(sinks)), chain * M + mode] = 1.0
+    chain_ix, chain_ok = _padded_groups(chain, C)
+    mode_ix, mode_ok = _padded_groups(mode, len(spans))
+    return SinkGroups(
+        pos=np.array(pos, dtype=np.int64),
+        t0=np.array(t0, dtype=np.float64),
+        ddl=np.array(ddl, dtype=np.float64),
+        modes=tuple(modes),
+        chain_mode=chain_mode,
+        chain_ix=chain_ix,
+        chain_ok=chain_ok,
+        mode_ix=mode_ix,
+        mode_ok=mode_ok,
+        expected=np.array([expected[c] for c in chain_names], dtype=np.int64),
+        fill=tuple(
+            tuple((m_of[m], em[m]) for m in mode_order if m in em)
+            for em in (expected_mode[c] for c in chain_names)
+        ),
+    )
 
 
 def _policy_knobs(policy) -> Tuple[bool, bool, bool, float]:
@@ -562,6 +637,7 @@ def build_problem(
         )
     n_switch = sum(1 for t, _m in bounds[1:] if t <= duration + _TOL)
 
+    mode_order = [m for m in scenario.modes()]
     reserved = sum((b - a) * tbl.peak_tiles for a, b, _m, tbl, _sw in segs)
     tiles_used = max(tbl.peak_tiles for tbl in [schedule0] + tables)
 
@@ -581,10 +657,14 @@ def build_problem(
         considered=considered,
         e2e_host=e2e_p,
         sinks=sinks,
+        sink_groups=_sink_groups(
+            sinks, [c.name for c in wf.chains], spans, expected,
+            expected_mode, mode_order,
+        ),
         chain_names=[c.name for c in wf.chains],
         expected=expected,
         expected_mode=expected_mode,
-        mode_order=[m for m in scenario.modes()],
+        mode_order=mode_order,
         seg_mode=[m for _a, _b, m, _t, _s in segs],
         seg_span=[(a, b) for a, b, _m, _t, _s in segs],
         spans=spans,
@@ -672,23 +752,41 @@ def run_problem(
 
 
 def _assemble_reports(problem: SoaProblem, out: Dict[str, np.ndarray]):
-    """One report per lane: the whole-array part, then each lane's."""
+    """One report per lane: reductions over all lanes at once, then
+    each lane's report objects from its rows."""
     with metrics.phase("soa_assemble_arrays"):
         arrays = _report_arrays(problem, out)
     with metrics.phase("soa_assemble_lanes"):
-        return [
-            _lane_report(problem, out, arrays, k) for k in range(problem.cfg.R)
-        ]
+        return [_lane_report(problem, arrays, k) for k in range(problem.cfg.R)]
+
+
+def _row_p99(x: np.ndarray, mask: np.ndarray) -> np.ndarray:
+    """``np.percentile(x[i][mask[i]], 99)`` for every row ``i`` of the
+    last axis at once, bit for bit (NumPy's ``linear`` method and its
+    ``_lerp``); nan where a row has no masked value."""
+    n = mask.sum(axis=-1)
+    if x.shape[-1] == 0:
+        return np.full(n.shape, np.nan)
+    s = np.sort(np.where(mask, x, np.inf), axis=-1)
+    v = (n - 1) * 0.99
+    lo = np.floor(v)
+    t = v - lo
+    i = np.maximum(lo.astype(np.intp), 0)
+    j = np.minimum(i + 1, np.maximum(n - 1, 0))
+    a = np.take_along_axis(s, i[..., None], axis=-1)[..., 0]
+    b = np.take_along_axis(s, j[..., None], axis=-1)[..., 0]
+    a[n == 0] = np.nan
+    d = b - a
+    return np.where(t >= 0.5, b - d * (1 - t), a + d * t)
 
 
 def _report_arrays(problem: SoaProblem, out: Dict[str, np.ndarray]) -> dict:
-    """Every lane's outcome per sink, and the tile-second totals, as
-    whole arrays."""
+    """Every lane's report fields as reductions over ``(R, sinks)``,
+    then as Python values by lane for :func:`_lane_report`."""
     R = problem.cfg.R
-    dur = problem.duration
-    total = problem.num_tiles * dur
+    g = problem.sink_groups
+    C, M, Ms = len(problem.chain_names), len(g.modes), len(problem.spans)
     cons = problem.considered
-    n_jobs = int(np.sum(cons))
     state = out["state"]
     fin = out["fin"].astype(np.float64)
     deg = out["deg"] > 0.5
@@ -699,132 +797,112 @@ def _report_arrays(problem: SoaProblem, out: Dict[str, np.ndarray]) -> dict:
     n_dropped = dropped.sum(axis=1)
     n_miss = n_dropped + late.sum(axis=1) + unfinished.sum(axis=1)
 
-    # per-sink vectors across lanes
-    sink_pos = np.array([p for _c, p, _t, _d, _m in problem.sinks], dtype=np.int64)
-    sink_t0 = np.array([t for _c, _p, t, _d, _m in problem.sinks])
-    sink_ddl = np.array([d for _c, _p, _t, d, _m in problem.sinks])
-    st_s = state[:, sink_pos] if len(sink_pos) else np.zeros((R, 0))
-    fin_s = fin[:, sink_pos] if len(sink_pos) else np.zeros((R, 0))
-    deg_s = deg[:, sink_pos] if len(sink_pos) else np.zeros((R, 0), bool)
-    lat_s = fin_s - sink_t0[None, :]
+    # each lane's outcome per sink
+    st_s = state[:, g.pos]
+    lat_s = fin[:, g.pos] - g.t0[None, :]
     done_s = st_s == K.DONE
     drop_s = st_s == K.DROP
-    viol_s = done_s & ((lat_s > sink_ddl[None, :] + 1e-9) | deg_s)
+    viol_s = done_s & ((lat_s > g.ddl[None, :] + 1e-9) | deg[:, g.pos])
 
-    seg_mode = problem.seg_mode
-    busy_seg = out["busy"]
-    rel_seg = out["realloc"]
-    busy_tot = busy_seg.sum(axis=1)
-    rel_tot = rel_seg.sum(axis=1)
-    mode_busy: Dict[str, np.ndarray] = {}
-    mode_rel: Dict[str, np.ndarray] = {}
-    for s, m in enumerate(seg_mode):
-        mode_busy[m] = mode_busy.get(m, 0.0) + busy_seg[:, s]
-        mode_rel[m] = mode_rel.get(m, 0.0) + rel_seg[:, s]
-
-    return {
-        "total": total, "n_jobs": n_jobs, "n_dropped": n_dropped,
-        "n_miss": n_miss, "lat_s": lat_s, "done_s": done_s,
-        "drop_s": drop_s, "viol_s": viol_s, "mode_busy": mode_busy,
-        "mode_rel": mode_rel, "busy_tot": busy_tot, "rel_tot": rel_tot,
-    }
-
-
-def _lane_report(
-    problem: SoaProblem, out: Dict[str, np.ndarray], a: dict, k: int
-) -> SimReport:
-    """Lane ``k``'s report from :func:`_report_arrays`."""
-    dur, total, n_jobs = problem.duration, a["total"], a["n_jobs"]
-    done_s, drop_s, viol_s, lat_s = a["done_s"], a["drop_s"], a["viol_s"], a["lat_s"]
-    mode_busy, mode_rel = a["mode_busy"], a["mode_rel"]
-    chain_count = {c: 0 for c in problem.chain_names}
-    chain_viol = {c: 0 for c in problem.chain_names}
-    chain_lats: Dict[str, List[float]] = {c: [] for c in problem.chain_names}
-    sink_by_mode: Dict[Tuple[str, str], List[int]] = {}
-    mode_lats: Dict[str, List[float]] = {}
-    for i, (cname, _p, t0, _ddl, m) in enumerate(problem.sinks):
-        if done_s[k, i]:
-            chain_count[cname] += 1
-            chain_viol[cname] += int(viol_s[k, i])
-            chain_lats[cname].append(float(lat_s[k, i]))
-            rec = sink_by_mode.setdefault((cname, m), [0, 0])
-            rec[0] += 1
-            rec[1] += int(viol_s[k, i])
-            mode_lats.setdefault(m, []).append(float(lat_s[k, i]))
-        elif drop_s[k, i]:
-            chain_count[cname] += 1
-            chain_viol[cname] += 1
-            rec = sink_by_mode.setdefault((cname, m), [0, 0])
-            rec[0] += 1
-            rec[1] += 1
+    # per (chain, mode): [completed or dropped, violated or dropped]
+    flags = np.concatenate([done_s, drop_s, viol_s]).astype(np.float64)
+    sums = (flags @ g.chain_mode).astype(np.int64).reshape(3, R, C, M)
+    rec_n = sums[0] + sums[1]
+    rec_v = sums[2] + sums[1]
+    count = rec_n.sum(axis=2)
+    viol = rec_v.sum(axis=2)
 
     # starvation deficits, reconciled chronologically per mode
-    for cname in problem.chain_names:
-        deficit = max(0, problem.expected[cname] - chain_count[cname])
-        if not deficit:
-            continue
-        chain_viol[cname] += deficit
-        chain_count[cname] = problem.expected[cname]
-        em = problem.expected_mode[cname]
-        for m in problem.mode_order:
-            if m not in em:
-                continue
-            rec = sink_by_mode.setdefault((cname, m), [0, 0])
-            take = min(max(0, em[m] - rec[0]), deficit)
-            if take:
-                rec[0] += take
-                rec[1] += take
-                deficit -= take
-            if not deficit:
+    deficit = np.maximum(g.expected[None, :] - count, 0)
+    count += deficit
+    viol += deficit
+    lanes, chains = np.nonzero(deficit)
+    for k, c, d in zip(lanes.tolist(), chains.tolist(),
+                       deficit[lanes, chains].tolist()):
+        for j, em in g.fill[c]:
+            take = min(max(0, em - int(rec_n[k, c, j])), d)
+            rec_n[k, c, j] += take
+            rec_v[k, c, j] += take
+            d -= take
+            if not d:
                 break
+    metrics.count("soa_assemble_deficit_lanes", len(np.unique(lanes)))
 
-    p99 = {
-        c: (float(np.percentile(ls, 99)) if ls else float("nan"))
-        for c, ls in chain_lats.items()
+    # latencies of the completed sinks: per chain in sink order, per mode
+    lat_c = lat_s[:, g.chain_ix]
+    done_c = done_s[:, g.chain_ix] & g.chain_ok
+    lat_ends = np.zeros(R * C + 1, dtype=np.int64)
+    np.cumsum(done_c.sum(axis=2), out=lat_ends[1:])
+    mode_p99 = _row_p99(lat_s[:, g.mode_ix], done_s[:, g.mode_ix] & g.mode_ok)
+
+    busy_seg, rel_seg = out["busy"], out["realloc"]
+    mode_busy = np.zeros((R, Ms))
+    mode_rel = np.zeros((R, Ms))
+    for s, m in enumerate(problem.seg_mode):
+        j = g.modes.index(m)
+        if j < Ms:
+            mode_busy[:, j] += busy_seg[:, s]
+            mode_rel[:, j] += rel_seg[:, s]
+
+    return {
+        "n_jobs": int(np.sum(cons)),
+        "n_dropped": n_dropped.tolist(),
+        "n_miss": n_miss.tolist(),
+        "count": count.tolist(),
+        "viol": viol.tolist(),
+        "p99": _row_p99(lat_c, done_c).tolist(),
+        "lat_flat": lat_c[done_c].tolist(),
+        "lat_ends": lat_ends.tolist(),
+        "mode_n": rec_n.sum(axis=1)[:, :Ms].tolist(),
+        "mode_v": rec_v.sum(axis=1)[:, :Ms].tolist(),
+        "mode_p99": mode_p99.tolist(),
+        "mode_busy": mode_busy.tolist(),
+        "mode_rel": mode_rel.tolist(),
+        "busy": busy_seg.sum(axis=1).tolist(),
+        "rel": rel_seg.sum(axis=1).tolist(),
+        "dropped_work": out["dropped_work"].tolist(),
+        "n_realloc": out["n_realloc"].tolist(),
+        "realloc_bytes": out["realloc_bytes"].tolist(),
     }
+
+
+def _lane_report(problem: SoaProblem, a: dict, k: int) -> SimReport:
+    """Lane ``k``'s report from the rows of :func:`_report_arrays`."""
+    dur, n_jobs = problem.duration, a["n_jobs"]
+    total = problem.num_tiles * dur
+    names = problem.chain_names
+    flat, ends, at = a["lat_flat"], a["lat_ends"], k * len(names)
     mode_stats: Dict[str, ModeStats] = {}
-    for m, span in problem.spans.items():
-        done_m = sum(
-            rec[0] for (_c, mm), rec in sink_by_mode.items() if mm == m
-        )
-        viol_m = sum(
-            rec[1] for (_c, mm), rec in sink_by_mode.items() if mm == m
-        )
-        lats = mode_lats.get(m, [])
+    for j, (m, span) in enumerate(problem.spans.items()):
         denom = problem.num_tiles * span
-        mb = float(np.asarray(mode_busy.get(m, 0.0))[k]) if m in mode_busy else 0.0
-        mr = float(np.asarray(mode_rel.get(m, 0.0))[k]) if m in mode_rel else 0.0
         mode_stats[m] = ModeStats(
             mode=m,
             span_s=span,
-            n_completed=done_m,
-            n_violations=viol_m,
-            p99_s=(
-                float(np.percentile(np.asarray(lats), 99))
-                if lats else float("nan")
-            ),
-            effective_frac=mb / denom if denom > 0 else 0.0,
-            realloc_frac=mr / denom if denom > 0 else 0.0,
+            n_completed=a["mode_n"][k][j],
+            n_violations=a["mode_v"][k][j],
+            p99_s=a["mode_p99"][k][j],
+            effective_frac=a["mode_busy"][k][j] / denom if denom > 0 else 0.0,
+            realloc_frac=a["mode_rel"][k][j] / denom if denom > 0 else 0.0,
         )
-
-    busy = float(a["busy_tot"][k])
-    rel_ts = float(a["rel_tot"][k])
+    busy, rel_ts = a["busy"][k], a["rel"][k]
     return SimReport(
         duration_s=dur,
         total_tiles=problem.num_tiles,
         effective_frac=busy / total,
         realloc_frac=rel_ts / total,
         idle_frac=max(0.0, 1.0 - (busy + rel_ts) / total),
-        dropped_work_frac=float(out["dropped_work"][k]) / total,
-        n_realloc=int(round(float(out["n_realloc"][k]))),
-        realloc_bytes=float(out["realloc_bytes"][k]),
+        dropped_work_frac=a["dropped_work"][k] / total,
+        n_realloc=int(round(a["n_realloc"][k])),
+        realloc_bytes=a["realloc_bytes"][k],
         n_jobs=n_jobs,
-        n_dropped=int(a["n_dropped"][k]),
-        task_miss_rate=float(a["n_miss"][k]) / max(n_jobs, 1),
-        chain_count=chain_count,
-        chain_violations=chain_viol,
-        chain_p99_s=p99,
-        chain_latencies=chain_lats,
+        n_dropped=a["n_dropped"][k],
+        task_miss_rate=a["n_miss"][k] / max(n_jobs, 1),
+        chain_count=dict(zip(names, a["count"][k])),
+        chain_violations=dict(zip(names, a["viol"][k])),
+        chain_p99_s=dict(zip(names, a["p99"][k])),
+        chain_latencies={
+            c: flat[ends[at + i]: ends[at + i + 1]] for i, c in enumerate(names)
+        },
         decision_ratios=[],
         mode_stats=mode_stats,
         n_mode_switches=problem.n_mode_switches,
