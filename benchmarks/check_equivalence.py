@@ -1,5 +1,10 @@
 """CI equivalence gate: batched lockstep engine vs the scalar engine.
 
+Tier-1 checks both contracts on every nominal cell
+(``tests/test_batch.py``, ``tests/test_soa_contract.py``); this CLI
+repeats the sweep in CI at its own seeds, writes a pass/fail table,
+and adds the lockstep speedup floor.
+
 Usage::
 
     python -m benchmarks.check_equivalence \

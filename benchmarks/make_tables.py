@@ -1,6 +1,5 @@
-"""Generate the EXPERIMENTS.md §Dry-run / §Roofline markdown tables from
-the dry-run artifacts, plus the §Scenarios table from any saved
-scenario/rate-sweep runs:  PYTHONPATH=src python -m benchmarks.make_tables
+"""Render the §Scenarios markdown table from saved scenario/rate-sweep
+runs:  PYTHONPATH=src python -m benchmarks.make_tables
 
 Scenario inputs are the JSON files written by
 ``python -m benchmarks.run
@@ -8,21 +7,14 @@ Scenario inputs are the JSON files written by
 --out benchmarks/results/scenarios/<name>.json`` (CI uploads one per
 run as a workflow artifact — including the weekly extended sweep; drop
 downloaded artifacts into that directory to render them alongside the
-paper tables).
+other runs).
 """
 from __future__ import annotations
 
 import json
 from pathlib import Path
 
-RESULTS = Path(__file__).resolve().parent / "results" / "dryrun"
 SCENARIOS = Path(__file__).resolve().parent / "results" / "scenarios"
-
-
-def fmt_bytes(n):
-    if n is None:
-        return "-"
-    return f"{n/1e9:.2f}"
 
 
 def _derived_map(derived: str) -> dict:
@@ -35,7 +27,7 @@ def _derived_map(derived: str) -> dict:
     return out
 
 
-def scenario_tables() -> None:
+def main() -> None:
     files = sorted(SCENARIOS.glob("*.json"))
     if not files:
         return
@@ -60,44 +52,6 @@ def scenario_tables() -> None:
                 f"| {kv.get('realloc', '-')} | {kv.get('switches', '-')} "
                 f"| {per_mode or '-'} |"
             )
-
-
-def main() -> None:
-    rows = []
-    for p in sorted(RESULTS.glob("*.json")):
-        rows.append(json.loads(p.read_text()))
-
-    print("### §Dry-run (per-device memory, from compiled.memory_analysis())\n")
-    print("| arch | shape | mesh | status | args GB | temp GB | out GB |")
-    print("|---|---|---|---|---|---|---|")
-    for d in rows:
-        m = d.get("memory", {})
-        print(
-            f"| {d['arch']} | {d['shape']} | {d.get('mesh','-')} "
-            f"| {d['status']} "
-            f"| {fmt_bytes(m.get('argument_bytes_per_device'))} "
-            f"| {fmt_bytes(m.get('temp_bytes_per_device'))} "
-            f"| {fmt_bytes(m.get('output_bytes_per_device'))} |"
-        )
-
-    print("\n### §Roofline (three terms per cell; v5e constants)\n")
-    print("| arch | shape | mesh | compute ms | memory ms | collective ms "
-          "| dominant | useful/HLO | roofline frac |")
-    print("|---|---|---|---|---|---|---|---|---|")
-    for d in rows:
-        if d.get("status") != "OK":
-            print(f"| {d['arch']} | {d['shape']} | {d.get('mesh','-')} "
-                  f"| {d['status']} | | | | | |")
-            continue
-        r = d["roofline"]
-        print(
-            f"| {r['arch']} | {r['shape']} | {r['mesh']} "
-            f"| {r['compute_s']*1e3:.2f} | {r['memory_s']*1e3:.2f} "
-            f"| {r['collective_s']*1e3:.2f} | {r['dominant']} "
-            f"| {r['useful_flops_ratio']:.3f} | {r['roofline_fraction']:.4f} |"
-        )
-
-    scenario_tables()
 
 
 if __name__ == "__main__":

@@ -1,14 +1,13 @@
 # One function per paper table. Print ``name,us_per_call,derived`` CSV.
 """Benchmark harness: ``PYTHONPATH=src python -m benchmarks.run``.
 
-One module per paper artifact (DESIGN.md §7):
+One module per artifact of the paper's §V, plus the scenario suites:
   fig6  — Cyc./Tp-driven characterization (paper Fig. 6)
   fig11 — ablations: reservation, partitioning, their interplay (Fig. 11)
   fig12 — E2E tail latency + violation rate vs tiles (Fig. 12)
   fig13 — scaling: max chains / min tiles / waste (Fig. 13)
   figS  — driving scenarios: mode switches, replanning, MC sweeps
   table2 — scheduling-decision vs resharding overhead (Table II)
-  roofline — §Roofline table from the dry-run artifacts
 
 ``--only fig11`` runs a subset; ``--duration`` scales simulated seconds
 (default keeps the full harness under ~15 min on one CPU core);
@@ -31,7 +30,7 @@ from repro.obs import metrics
 
 from . import fig6_casestudy, fig11_ablation, fig12_e2e, fig13_scaling
 from . import figS_budget, figS_degrade, figS_predict, figS_rates
-from . import figS_scenarios, headroom, perf_bench, roofline, table2_overhead
+from . import figS_scenarios, headroom, perf_bench, table2_overhead
 
 SUITES = {
     "fig6": fig6_casestudy.run,
@@ -46,7 +45,6 @@ SUITES = {
     "perf": perf_bench.run,
     "table2": table2_overhead.run,
     "headroom": headroom.run,
-    "roofline": roofline.run,
 }
 
 #: CLI conveniences: the scenario suites also answer to their module names

@@ -1,14 +1,9 @@
 """Hardware model for tile-based accelerators (paper §II-C1, §V-A).
 
-Two instantiations ship with the framework:
-
-* :func:`simba_chip` — the paper's evaluation platform (Simba-derived,
-  128 tiles @ 2 GHz, 16 PE x 16 MAC per tile, 1.25 MB SRAM/tile, 64 B NoC
-  links, LPDDR5 @ 102 GB/s).  Used by the faithful reproduction
-  (Tile-stream simulator + GHA compiler + benchmarks).
-* :func:`tpu_pod` — the TPU adaptation where a "tile" is one TPU v5e chip
-  and the NoC is the ICI torus.  Used by the serving engine and the
-  multi-pod launch path (see DESIGN.md §3).
+:func:`simba_chip` instantiates the paper's evaluation platform
+(Simba-derived, 128 tiles @ 2 GHz, 16 PE x 16 MAC per tile, 1.25 MB
+SRAM/tile, 64 B NoC links, LPDDR5 @ 102 GB/s), used by the Tile-stream
+simulator, the GHA compiler and the benchmarks.
 
 The scheduler stack is hardware-agnostic: everything consumes a
 :class:`HardwareModel`.
@@ -22,7 +17,6 @@ from typing import Tuple
 __all__ = [
     "HardwareModel",
     "simba_chip",
-    "tpu_pod",
     "ReallocCostModel",
 ]
 
@@ -143,27 +137,3 @@ def simba_chip(num_tiles: int = 128) -> HardwareModel:
         return base
     return base.scaled(num_tiles)
 
-
-def tpu_pod(num_chips: int = 256) -> HardwareModel:
-    """TPU adaptation: one 'tile' = one v5e chip (DESIGN.md §3).
-
-    197 bf16 TFLOP/s and 819 GB/s HBM per chip; ICI links ~50 GB/s.
-    Reallocation = resharding params/KV over ICI.
-    """
-    rows = int(math.sqrt(num_chips))
-    while num_chips % rows:
-        rows -= 1
-    return HardwareModel(
-        name=f"tpu-v5e-{num_chips}c",
-        num_tiles=num_chips,
-        mesh_shape=(rows, num_chips // rows),
-        tile_flops=197e12,
-        tile_sram_bytes=16e9,                    # HBM plays the SRAM role
-        noc_link_bytes_per_s=50e9,
-        dram_bw_bytes_per_s=819e9 * num_chips,
-        num_memory_controllers=num_chips,
-        freq_hz=0.94e9,
-        realloc=ReallocCostModel(
-            decision_s=5e-6, per_hop_s=1e-6, migration_bw=50e9, fixed_s=100e-6
-        ),
-    )

@@ -1,7 +1,6 @@
 import os
 
-# tests and benches run single-device (the 512-device flag is set ONLY
-# inside repro.launch.dryrun, never globally)
+# tests and benches run JAX on the host CPU
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import jax  # noqa: E402

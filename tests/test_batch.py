@@ -4,24 +4,32 @@ The contract under test is bit-identity: every lane of a seed-fan or
 group ``run(..., backend="lockstep")`` must produce a
 :class:`~repro.core.sim.engine.SimReport` exactly equal (via
 ``report_digest``, every float verbatim) to the same run through the
-scalar backend.  The full bundled-scenario sweep runs in
-CI as its own gate (``benchmarks.check_equivalence``); here a fast
-subset pins the contract into tier-1, plus the de-batching edge cases
-(unsupported lane, attached recorder) and a property test over random
-scenarios/workloads.
+scalar backend.  Every bundled scenario without degradations x every
+policy x both drop policies is checked here (``benchmarks.check_equivalence``
+repeats the sweep in CI; ``test_degrade.py`` covers the degraded
+scenario), plus the de-batching edge cases (unsupported lane, attached
+recorder) and a property test over random scenarios/workloads.
 """
 import dataclasses
 
 import numpy as np
 import pytest
 
+from repro.core.experiment import POLICIES
 from repro.core.sim import batch as batch_mod
 from repro.core.sim.batch import reports_identical
 from repro.obs import TraceRecorder
 from repro.scenarios.runner import ScenarioSpec, run
-from repro.scenarios.script import default_generator, get_scenario
+from repro.scenarios.script import (
+    BUNDLED_SCENARIOS,
+    default_generator,
+    get_scenario,
+)
 
 SEEDS = [0, 7]
+NOMINAL_SCENARIOS = tuple(
+    name for name, s in BUNDLED_SCENARIOS.items() if not s.has_degradations
+)
 
 
 def _scalar(spec: ScenarioSpec, seed: int):
@@ -41,13 +49,21 @@ def _spy_scalar_lanes(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("policy", ["cyc", "tp_driven", "ads_tile"])
-@pytest.mark.parametrize("scenario", ["calm_to_rush", "rate_churn"])
-def test_batched_reports_bit_identical(scenario, policy):
-    spec = ScenarioSpec(scenario=get_scenario(scenario), policy=policy)
+@pytest.mark.parametrize("drop_policy", ["soft", "hard"])
+@pytest.mark.parametrize("policy", POLICIES)
+@pytest.mark.parametrize("scenario", NOMINAL_SCENARIOS)
+def test_batched_reports_bit_identical(scenario, policy, drop_policy):
+    spec = ScenarioSpec(
+        scenario=get_scenario(scenario), policy=policy, drop_policy=drop_policy
+    )
     reports = run(spec, seeds=SEEDS, backend="lockstep")
     for s, rb in zip(SEEDS, reports):
-        assert reports_identical(_scalar(spec, s), rb), (scenario, policy, s)
+        assert reports_identical(_scalar(spec, s), rb), (
+            scenario,
+            policy,
+            drop_policy,
+            s,
+        )
 
 
 def test_divergent_lane_falls_back_to_scalar(monkeypatch):

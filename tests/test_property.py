@@ -8,7 +8,6 @@ import pytest
 hypothesis = pytest.importorskip("hypothesis")
 from hypothesis import HealthCheck, given, settings, strategies as st  # noqa: E402
 
-from repro.analysis.hlo import collective_bytes, parse_hlo_collectives
 from repro.core.gha.guillotine import guillotine_cut
 from repro.core.latency_model import LogNormal, ShiftedExponential
 from repro.core.runtime import fit_quota
@@ -136,29 +135,3 @@ def test_unroll_instance_counts(r1, r2):
     for i in insts:
         for dep in i.preds:
             assert by_key[dep].release_s <= i.release_s + 1e-12
-
-
-# ---------------------------------------------------------------------------
-@given(
-    n=st.integers(1, 5),
-    dt=st.sampled_from(["f32", "bf16"]),
-    dims=st.tuples(st.integers(1, 64), st.integers(1, 128)),
-)
-@SET
-def test_hlo_collective_parser(n, dt, dims):
-    a, b = dims
-    nbytes = a * b * (4 if dt == "f32" else 2)
-    lines = ["HloModule m", "ENTRY %main {"]
-    lines.append(f"  %p0 = {dt}[{a},{b}]{{1,0}} parameter(0)")
-    for i in range(n):
-        lines.append(
-            f"  %all-reduce.{i} = {dt}[{a},{b}]{{1,0}} all-reduce(%p0), "
-            "replica_groups={}, to_apply=%add"
-        )
-    lines.append(f"  ROOT %t = ({dt}[{a},{b}]{{1,0}}) tuple(%all-reduce.0)")
-    lines.append("}")
-    agg = collective_bytes("\n".join(lines))
-    assert agg["all-reduce"] == n * nbytes
-    assert agg["total"] == n * nbytes
-    recs = parse_hlo_collectives("\n".join(lines))
-    assert len(recs) == n

@@ -1,24 +1,15 @@
 """Structure-of-arrays jax backend vs the scalar reference engine.
 
-The contract under test is *distributional* equivalence, not
+The backend's contract is *distributional* equivalence, not
 bit-identity (``docs/performance.md#soa-backend``): the SoA kernels
 replace the event heap with discrete scheduling rounds, so individual
 event timestamps shift at round granularity while the statistics the
-paper's claims rest on must agree.  Per cell the tests assert
-
-* exact equality of structural invariants (job universe, seam spans,
-  chain universe, reservation footprint) per seed,
-* a pooled chain-latency KS statistic inside the measured dt=1e-3
-  approximation envelope (at most ~0.06, for the policies that decide
-  at a round's end),
-* CI overlap on violation rate and realloc waste.
-
-The full bundled-scenario sweep runs in CI as its own gate
-(``benchmarks.check_equivalence --mode distributional``); here one
-scenario pins the contract into tier-1 per policy, plus support
-predicates, the device sampling path, the allocator reference kernel,
-and a property test over random Markov scenarios mirroring
-``test_batch.py``.
+paper's claims rest on must agree.  ``test_soa_contract.py`` holds
+every nominal cell to it and ``test_soa_pins.py`` pins the round
+loop's outputs; here are the kernel cache, the window-overflow retry,
+the support predicates, the device sampling path, the allocator and
+pick kernels, tp_driven's decision instants, and a property test over
+random Markov scenarios mirroring ``test_batch.py``.
 """
 import dataclasses
 import os
@@ -33,45 +24,7 @@ from repro.core.sim.batch import sample_trace_batch
 from repro.core.sim.engine import Job, JobState
 from repro.scenarios.runner import ScenarioSpec, run
 from repro.scenarios.script import default_generator, get_scenario
-
-SEEDS = [0, 1, 2, 3]
-
-#: KS gate for the tier-1 subset: cyc and ads_tile decide at each
-#: round's end, and their measured dt=1e-3 envelope across the bundled
-#: cells is 0.01-0.06; tp_driven decides at its queue-change instants
-#: and reads under 0.01.  0.08 trips on regression, not on the known
-#: round bias
-KS_TOL = 0.08
-
-
-def _cell(scenario: str, policy: str, seeds=SEEDS):
-    spec = ScenarioSpec(scenario=get_scenario(scenario), policy=policy)
-    ref = [r for s in seeds for r in
-           run(dataclasses.replace(spec, seed=int(s)), backend="scalar")]
-    got = run(spec, seeds=seeds, backend="soa", fallback=False)
-    return ref, got
-
-
-def _pooled_latencies(reports):
-    return [x for r in reports for ls in r.chain_latencies.values() for x in ls]
-
-
-# ---------------------------------------------------------------------------
-# equivalence contract, per policy
-# ---------------------------------------------------------------------------
-@pytest.mark.parametrize("policy", ["cyc", "tp_driven", "ads_tile"])
-def test_soa_distributionally_equivalent(policy):
-    ref, got = _cell("commute", policy)
-    for a, b in zip(ref, got):
-        ia, ib = soa.structural_invariants(a), soa.structural_invariants(b)
-        assert ia == ib, {f: (ia[f], ib[f]) for f in ia if ia[f] != ib[f]}
-    ks = soa.ks_statistic(_pooled_latencies(ref), _pooled_latencies(got))
-    assert ks <= KS_TOL, f"{policy}: pooled chain-latency KS {ks:.4f} > {KS_TOL}"
-    for metric in ("violation_rate", "realloc_frac"):
-        ci_ref = soa.mean_ci([getattr(r, metric) for r in ref])
-        ci_got = soa.mean_ci([getattr(r, metric) for r in got])
-        assert soa.intervals_overlap(ci_ref, ci_got, pad=1e-9), (
-            metric, ci_ref, ci_got)
+from test_soa_contract import SEEDS, _pooled_latencies
 
 
 # ---------------------------------------------------------------------------
@@ -575,45 +528,3 @@ def test_tp_driven_walks_at_each_instant_of_a_hand_built_round():
         fin, [0.6 * ms + stall + (1 - 0.08) * 0.01, 0.6 * ms + stall + 0.01],
         rtol=1e-5)
 
-
-#: 16-hex digests of every round-loop output of a run at R = 8, the
-#: overflow retries' included, taken before tp_driven moved to its
-#: decision instants: cyc's and ads_tile's loops are separate static
-#: programs and must not move.  The digests hash raw float bytes from
-#: the CPU backend, so they hold for the pinned jax version
-#: (requirements.txt) and may move with another jax, XLA or host
-#: instruction set even where the programs do not
-PINNED_LOOPS = {
-    ("commute", "cyc"): (1, "ade6b8989ebb652d"),
-    ("commute", "ads_tile"): (2, "291578329beea112"),
-    ("rate_churn", "cyc"): (1, "875d6163084ef997"),
-    ("rate_churn", "ads_tile"): (2, "0e3daca9015d0f5e"),
-}
-LOOP_KEYS = ("state", "ready_t", "deg", "start", "fin", "dop", "codes",
-             "busy", "realloc", "n_realloc", "realloc_bytes", "dropped_work")
-
-
-@pytest.mark.parametrize(("scenario", "policy"), sorted(PINNED_LOOPS))
-def test_cyc_and_ads_tile_loops_are_pinned(scenario, policy, monkeypatch):
-    import hashlib
-
-    spec = ScenarioSpec(scenario=get_scenario(scenario), policy=policy)
-    calls = []
-    real = K.simulate
-
-    def recording(cfg, const_np, lanes_np):
-        calls.append(real(cfg, const_np, lanes_np))
-        return calls[-1]
-
-    monkeypatch.setattr(K, "simulate", recording)
-    K.clear_kernel_cache()
-    run(spec, seeds=list(range(8)), backend="soa", fallback=False,
-        options=soa.SoaOptions())
-    h = hashlib.sha1()
-    for out in calls:
-        for k in LOOP_KEYS:
-            a = np.ascontiguousarray(out[k])
-            h.update(k.encode())
-            h.update(str(a.dtype).encode())
-            h.update(a.tobytes())
-    assert (len(calls), h.hexdigest()[:16]) == PINNED_LOOPS[(scenario, policy)]

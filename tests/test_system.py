@@ -1,7 +1,7 @@
 """End-to-end behaviour tests for the paper's system (ADS-Tile).
 
 These assert the paper's headline claims hold on the regenerated
-benchmark (DESIGN.md §7): bounded reallocation waste, the
+benchmark (the paper's §V-A, ``core/benchmark.py``): bounded reallocation waste, the
 isolation/sharing trade-off, and E2E deadline behaviour.
 """
 import numpy as np
